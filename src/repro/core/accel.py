@@ -77,6 +77,10 @@ class AcceleratorDescription:
     #: target-specific graph-rewrite rules (repro.core.rewrite.RewriteRule);
     #: the frontend pipeline runs them right after generic legalization
     rewrite_rules: list = field(default_factory=list)
+    #: fast memory each Pallas kernel asks its compiler for
+    #: (``vmem_limit_bytes``); None keeps the compiler's default.  The arch's
+    #: buffered level, the scheduler's budget, must fit inside it.
+    kernel_vmem_limit_bytes: int | None = None
 
     # -- registration decorators (paper Fig. 3) ----------------------------
     def register_preprocessing(

@@ -113,6 +113,10 @@ class HardwareConstraints:
     * ``double_buffer_candidates`` — double-buffering settings to sweep;
       when True the scheduler halves every operand's usable share (paper
       §3.1: "we halve the maximum available memory for each operand").
+    * ``accumulator_bytes`` — per output element, the bytes of an
+      accumulator scratch the kernel keeps (single-buffered) beside the Out
+      tile; it is counted in the Out share (TPU: the Pallas GEMM's f32/int32
+      VMEM accumulator).
     """
 
     pe_dim: int
@@ -127,6 +131,16 @@ class HardwareConstraints:
         (1 / 8, 3 / 4, 1 / 8),
     )
     double_buffer_candidates: tuple[bool, ...] = (True, False)
+    accumulator_bytes: int = 0
+
+    def buffered_elem_bytes(
+        self, workload: GemmWorkload, op: str, double_buffer: bool
+    ) -> int:
+        """Bytes one element of operand ``op``'s tile occupies in a buffered
+        level: its width, twice when double-buffered, plus the accumulator
+        scratch beside an Out tile."""
+        n = workload.elem_bytes(op) * (2 if double_buffer else 1)
+        return n + (self.accumulator_bytes if op == "Out" else 0)
 
 
 @dataclass(frozen=True)
@@ -211,6 +225,7 @@ class ArchSpec:
                 "double_buffer_candidates": list(
                     self.constraints.double_buffer_candidates
                 ),
+                "accumulator_bytes": self.constraints.accumulator_bytes,
             },
             "dataflows": [dataclasses.asdict(d) for d in self.dataflows],
             "macs_per_cycle": self.macs_per_cycle,
@@ -251,6 +266,7 @@ class ArchSpec:
             double_buffer_candidates=tuple(
                 c.get("double_buffer_candidates", (True, False))
             ),
+            accumulator_bytes=c.get("accumulator_bytes", 0),
             **kwargs,
         )
         dataflows = tuple(

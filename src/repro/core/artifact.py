@@ -318,12 +318,15 @@ def save_module(
         # runs at load time — drop it to keep artifacts lean
         sd.pop("top", None)
         schedules[str(idx[n])] = sd
+    from repro.core.lowering import kernel_config_for, runs_pallas
+
     backend = module.backend
-    use_pallas = bool(getattr(backend, "use_pallas", False))
+    # what the steps actually run: a TPU description always takes Pallas
+    use_pallas = runs_pallas(
+        module.desc, bool(getattr(backend, "use_pallas", False))
+    )
     kernel_configs = {}
     if use_pallas and backend is not None:
-        from repro.core.lowering import kernel_config_for
-
         for n, op in module.ops.items():
             cfg = kernel_config_for(
                 module.desc, backend.mapping_gen, n, op.strategy

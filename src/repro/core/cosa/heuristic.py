@@ -40,7 +40,6 @@ def solve_heuristic(
     temporal = [dict.fromkeys(GEMM_DIMS, 1) for _ in range(num_levels)]
     spatial = [dict.fromkeys(GEMM_DIMS, 1) for _ in range(num_levels)]
     shares = dict(zip(OPERANDS, memory_shares))
-    mult = 2 if double_buffer else 1
 
     # --- PE level: spatial dims first (fill the array), then temporal. ----
     def pe_total(j: str) -> int:
@@ -67,10 +66,10 @@ def solve_heuristic(
     def fits(level: int) -> bool:
         lvl = arch.levels[level]
         for op in lvl.holds:
-            foot = workload.elem_bytes(op)
+            foot = c.buffered_elem_bytes(workload, op, double_buffer)
             for j in OPERAND_DIMS[op]:
                 foot *= tile(level, j)
-            if foot * mult > lvl.size_bytes * shares[op]:
+            if foot > lvl.size_bytes * shares[op]:
                 return False
         return True
 

@@ -73,15 +73,6 @@ class CosaMIP:
         self.factors = {j: prime_factors(self.padded_dims[j]) for j in GEMM_DIMS}
         self.num_levels = self.arch.num_levels
 
-    # ------------------------------------------------------------------
-    def _usable_share_bytes(self, level_idx: int, op: str) -> float:
-        lvl = self.arch.levels[level_idx]
-        share = dict(zip(OPERANDS, self.memory_shares))[op]
-        cap = lvl.size_bytes * share
-        if self.double_buffer:
-            cap /= 2.0  # paper: halve so each operand fits in half the memory
-        return cap
-
     def _buffer_level_for(self, op: str) -> int:
         for i in self.arch.buffered_levels():
             if op in self.arch.levels[i].holds:
@@ -152,13 +143,18 @@ class CosaMIP:
                 f"eq1_{j}",
             )
 
-        # (C4) memory capacity with uneven shares (+ double-buffer halving).
-        # log(tile footprint at level i) is linear in X over levels <= i.
+        # (C4) memory capacity with uneven shares.  Double buffering doubles
+        # each element's bytes (paper: each operand fits in half its share)
+        # and the Out tile carries the accumulator scratch.  log(tile
+        # footprint at level i) is linear in X over levels <= i.
+        shares = dict(zip(OPERANDS, self.memory_shares))
         for i in arch.buffered_levels():
             lvl = arch.levels[i]
             for op in lvl.holds:
-                cap = self._usable_share_bytes(i, op)
-                elem = wl.elem_bytes(op)
+                cap = lvl.size_bytes * shares[op]
+                elem = arch.constraints.buffered_elem_bytes(
+                    wl, op, self.double_buffer
+                )
                 if cap < elem:
                     return None  # share can't hold even one element
                 bound = math.log(cap / elem)

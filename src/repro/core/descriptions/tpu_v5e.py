@@ -8,10 +8,17 @@ hardware aligned.  This description drives the *same* extended-CoSA
 scheduler as Gemmini; its schedules are lowered by the mapping generator to
 ``pl.pallas_call`` grids + BlockSpecs instead of RoCC instructions.
 
-Hardware constants (per chip): 197 TFLOP/s bf16, 819 GB/s HBM, 128x128 MXU
-at ~940 MHz effective, ~64 MiB usable VMEM (we schedule against a
-conservative share to leave room for Mosaic's own buffers), ~50 GB/s/link
-ICI.
+Hardware constants (per chip): 197 TFLOP/s bf16 from four 128x128 MXUs at
+~1.5 GHz, 819 GB/s HBM, ~50 GB/s per ICI link.  VMEM is 128 MiB per core
+(JAX's ``tpu_info`` table for "TPU v5 lite"), but Mosaic grants a kernel only
+a 16 MiB scoped share unless the kernel asks for more.  Every kernel here
+asks for ``VMEM_LIMIT_BYTES`` (64 MiB).  The scheduler budgets 48 MiB of it
+and leaves ``MOSAIC_RESERVE_BYTES`` to Mosaic's internal scratch (up to
+~9 MiB at 2048-wide tiles) and the bias blocks.  Within the budget it
+counts what the kernel really holds: every block double-buffered (Mosaic's
+pipeline always does so) plus the f32/int32 accumulator scratch beside the
+output block.  Compiles for a described v5e chip hold both figures to
+account (``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
@@ -30,13 +37,16 @@ from repro.core.arch_spec import (
 MXU_DIM = 128
 LANE = 128  # last-dim tiling granularity
 SUBLANE = 8  # second-to-last-dim granularity (f32; bf16 is 16)
-VMEM_BYTES = 64 * 1024 * 1024
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+MOSAIC_RESERVE_BYTES = 16 * 1024 * 1024
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES - MOSAIC_RESERVE_BYTES
+ACC_BYTES = 4  # f32 / int32 accumulator scratch per output element
 HBM_GBPS = 819e9
 PEAK_BF16_FLOPS = 197e12
 ICI_LINK_GBPS = 50e9  # per link, ~4 links/chip on a 2D torus
 
 
-def make_tpu_v5e_arch(vmem_bytes: int = VMEM_BYTES) -> ArchSpec:
+def make_tpu_v5e_arch() -> ArchSpec:
     # 4 MXUs x 128x128 x 2 flops x 1.5 GHz ~= 197 TFLOP/s bf16.
     n_mxu = 4
     freq = PEAK_BF16_FLOPS / (2.0 * MXU_DIM * MXU_DIM * n_mxu)
@@ -47,7 +57,7 @@ def make_tpu_v5e_arch(vmem_bytes: int = VMEM_BYTES) -> ArchSpec:
             MemLevel("mxu", size_bytes=0, holds=(), bytes_per_cycle=0.0),
             MemLevel(
                 "vmem",
-                size_bytes=vmem_bytes,
+                size_bytes=VMEM_BUDGET_BYTES,
                 holds=("In", "W", "Out"),
                 bytes_per_cycle=HBM_GBPS / freq,  # HBM->VMEM bytes per cycle
             ),
@@ -66,7 +76,9 @@ def make_tpu_v5e_arch(vmem_bytes: int = VMEM_BYTES) -> ArchSpec:
                 (1 / 8, 5 / 8, 1 / 4),
                 (3 / 8, 1 / 8, 1 / 2),
             ),
-            double_buffer_candidates=(True, False),
+            # Mosaic's pipeline double-buffers every block
+            double_buffer_candidates=(True,),
+            accumulator_bytes=ACC_BYTES,
         ),
         dataflows=(OUTPUT_STATIONARY, WEIGHT_STATIONARY),
         macs_per_cycle=macs_per_cycle,
@@ -83,8 +95,12 @@ def make_tpu_v5e_arch(vmem_bytes: int = VMEM_BYTES) -> ArchSpec:
     )
 
 
-def make_tpu_v5e_description(vmem_bytes: int = VMEM_BYTES) -> AcceleratorDescription:
-    desc = AcceleratorDescription(name="tpu_v5e", arch=make_tpu_v5e_arch(vmem_bytes))
+def make_tpu_v5e_description() -> AcceleratorDescription:
+    desc = AcceleratorDescription(
+        name="tpu_v5e",
+        arch=make_tpu_v5e_arch(),
+        kernel_vmem_limit_bytes=VMEM_LIMIT_BYTES,
+    )
 
     # -- preprocessing: layout + (optional) quantization, folded when const --
     @desc.register_preprocessing("dense", operand="W", constant=True)

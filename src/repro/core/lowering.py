@@ -32,7 +32,6 @@ Epilogue attribute contract on generalized ops (set by the passes):
 
 from __future__ import annotations
 
-import os
 import threading
 from typing import Callable
 
@@ -75,13 +74,20 @@ def make_accel_executor(
                 f"generalized ops must provide them"
             )
 
-    if use_pallas or desc.name.startswith("tpu"):
+    if runs_pallas(desc, use_pallas):
         return _make_pallas_executor(
-            desc, mapping_gen, node, strategy, fused_epilogue, use_pallas
+            desc, mapping_gen, node, strategy, fused_epilogue
         )
     return _make_gemmini_executor(
         desc, mapping_gen, intrinsic_gen, node, strategy, fused_epilogue
     )
+
+
+def runs_pallas(desc: AcceleratorDescription, use_pallas: bool) -> bool:
+    """Whether accelerator steps run the scheduled Pallas kernel: always on a
+    TPU description, and on a described accelerator when the target asks
+    (otherwise it is emulated in numpy)."""
+    return use_pallas or desc.name.startswith("tpu")
 
 
 def resolved_fused_epilogue(node: Node, strategy: Strategy) -> bool:
@@ -135,13 +141,8 @@ def pallas_interpret_mode() -> bool:
 
     Interpret mode executes the same kernel, BlockSpecs, and grid in pure
     XLA-on-host, so CPU CI covers the exact tiling the cycle model priced;
-    on a TPU host the kernels compile through Mosaic.  Override with
-    ``REPRO_PALLAS_INTERPRET=0|1`` (e.g. to force interpret on a TPU VM
-    while debugging a kernel).
+    on a TPU host the kernels compile through Mosaic.
     """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.lower() not in ("0", "false", "no")
     import jax
 
     return jax.default_backend() != "tpu"
@@ -413,7 +414,6 @@ def _make_pallas_executor(
     node: Node,
     strategy: Strategy,
     fused_quant: bool,
-    use_pallas: bool,
 ) -> Callable:
     """Lower one accelerator step to the scheduled Pallas GEMM/qGEMM.
 
@@ -456,8 +456,8 @@ def _make_pallas_executor(
 
     def _run2d(x_j, w_j, b_j):
         if fused_quant:
-            return kops.qmatmul(x_j, w_j, b_j, cfg, use_pallas=use_pallas)
-        return kops.matmul(x_j, w_j, cfg, b_j, use_pallas=use_pallas)
+            return kops.qmatmul(x_j, w_j, b_j, cfg)
+        return kops.matmul(x_j, w_j, cfg, b_j)
 
     if pool:
         pool_size, pool_stride = pool["size"], pool["stride"]
@@ -496,4 +496,8 @@ def _make_pallas_executor(
             out = out + residual
         return out
 
+    # what the step runs: the bound kernel config, and the 2-D kernel call
+    # (x, w, bias) that ``jax.jit(...).lower`` can compile ahead of time
+    pallas_exec.kernel_config = cfg
+    pallas_exec.run_kernel = _run2d
     return pallas_exec

@@ -82,6 +82,7 @@ class MappingGenerator:
             activation=ep.get("activation"),
             has_bias=has_bias,
             interpret=interpret,
+            vmem_limit_bytes=self.desc.kernel_vmem_limit_bytes,
         )
 
     # -- Gemmini path: Schedule -> tensorized tiled executor ----------------

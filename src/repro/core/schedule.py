@@ -227,13 +227,17 @@ def validate_schedule(s: Schedule, arch: ArchSpec) -> list[str]:
             for j in GEMM_DIMS:
                 if s.spatial[i][j] != 1:
                     errs.append(f"spatial factor at non-spatial level {i} dim {j}")
-    # Memory capacity with uneven shares (+ double buffering halving).
+    # Memory capacity with uneven shares (+ double buffering halving and
+    # the accumulator scratch).
     shares = dict(zip(OPERANDS, s.memory_shares))
     for i in arch.buffered_levels():
         lvl = arch.levels[i]
         for op in lvl.holds:
             cap = lvl.size_bytes * shares[op]
-            used = s.tile_bytes(i, op) * (2 if s.double_buffer else 1)
+            elems = math.prod(s.tile(i, j) for j in OPERAND_DIMS[op])
+            used = elems * arch.constraints.buffered_elem_bytes(
+                s.workload, op, s.double_buffer
+            )
             if used > cap + 1e-6:
                 errs.append(
                     f"level {lvl.name} operand {op}: {used:,}B > share {cap:,.0f}B"

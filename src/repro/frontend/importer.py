@@ -57,7 +57,7 @@ SUPPORTED_PRIMITIVES: dict[str, str] = {
     "mul": "mul / dequantize (astype-float * scale idiom)",
     "max": "relu (maximum(x, 0) idiom)",
     "custom_jvp_call": "(inlined: jax.nn.relu, ...)",
-    "pjit": "(named: relu / clip / round / kv_cache_read / kv_cache_append; others inlined)",
+    "jit": "(named: relu / clip / round / kv_cache_read / kv_cache_append; others inlined)",
     "convert_element_type": "quantize / requantize chain sinks",
     "div": "quantize interior (round(x / scale) idiom)",
     "round": "quantize / requantize interior",
@@ -344,7 +344,7 @@ class _Importer:
         shape, dtype = tuple(aval.shape), str(aval.dtype)
         pend = lambda p=prim: _Pending(p, args, dict(eqn.params), shape, dtype)
 
-        if prim == "pjit":
+        if prim == "jit":
             return self.named_call(eqn, args)
         if prim == "custom_jvp_call":
             return self.inline(eqn.params["call_jaxpr"], args)
@@ -406,7 +406,7 @@ class _Importer:
         raise ValueError("unsupported primitive")
 
     def named_call(self, eqn, args) -> list:
-        """pjit: recognize the named jax.nn / jnp wrappers, inline the rest."""
+        """jit: recognize the named jax.nn / jnp wrappers, inline the rest."""
         closed = eqn.params["jaxpr"]
         name = eqn.params.get("name", "")
         aval = eqn.outvars[0].aval

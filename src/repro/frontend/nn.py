@@ -13,7 +13,7 @@ using the helpers just keeps the recognized form in one place:
     conv2d(x, w)      = NHWC/HWIO conv with wide int accumulation    -> ir.conv2d
 
 The two KV-cache helpers are the exception to "nothing here is special":
-they are ``jax.jit``-wrapped so the traced jaxpr carries a *named* pjit
+they are ``jax.jit``-wrapped so the traced jaxpr carries a *named* jit
 call the importer can map 1:1 onto the stateful IR ops:
 
     kv_cache_read(c)         = c (identity; marks state consumption) -> ir.kv_cache_read
@@ -80,10 +80,10 @@ def kv_cache_read(cache):
     """Materialize the KV cache for attention -> ``ir.kv_cache_read``.
 
     Numerically the identity; the ``jax.jit`` wrapper makes the call appear
-    in the jaxpr as a ``pjit`` equation named ``kv_cache_read``, which the
+    in the jaxpr as a ``jit`` equation named ``kv_cache_read``, which the
     importer maps 1:1 to the stateful IR op (same mechanism as the named
     ``relu``/``clip`` idioms).  A bare ``return cache`` would NOT survive:
-    jax forwards an identity jit's output var and leaves a dead pjit
+    jax forwards an identity jit's output var and leaves a dead jit
     equation with no outvars, so the body adds a scalar zero — bit-exact
     identity for every dtype, but a real equation the importer can see.
     """

@@ -22,12 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams across releases;
-# resolve whichever this jax ships so the kernel works on both sides.
-TPUCompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 
 @dataclass(frozen=True)
 class GemmKernelConfig:
@@ -46,6 +40,7 @@ class GemmKernelConfig:
     activation: str | None = None
     has_bias: bool = False
     interpret: bool = False
+    vmem_limit_bytes: int | None = None
 
     def grid_for(self, m: int, k: int, n: int) -> tuple[int, int, int]:
         gm, gk, gn = m // self.block_m, k // self.block_k, n // self.block_n
@@ -151,8 +146,9 @@ def scheduled_gemm(
         scratch_shapes=[
             pltpu.VMEM((cfg.block_m, cfg.block_n), jnp.dtype(cfg.acc_dtype))
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=cfg.vmem_limit_bytes,
         ),
         interpret=cfg.interpret,
     )(*operands)

@@ -14,11 +14,12 @@ cached by workload key inside the scheduler.
 from __future__ import annotations
 
 import threading
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import jax.numpy as jnp
 
 from repro.core.arch_spec import GemmWorkload
+from repro.core.lowering import pallas_interpret_mode
 from repro.core.mapping import MappingGenerator
 from repro.kernels.gemm import GemmKernelConfig
 
@@ -29,7 +30,8 @@ _POLICY: "ScheduledKernelPolicy | None" = None
 @dataclass
 class ScheduledKernelPolicy:
     backend: object  # repro.core.pipeline.CompilerBackend
-    interpret: bool = True  # CPU container: interpret; real TPU: False
+    # interpret-mode Pallas unless JAX's backend is a TPU
+    interpret: bool = field(default_factory=pallas_interpret_mode)
     min_m: int = 8  # skip degenerate GEMMs (decode gemv handled by XLA)
 
     def config_for(
@@ -68,7 +70,9 @@ def get_policy() -> ScheduledKernelPolicy | None:
 class scheduled_kernels:
     """Context manager: `with scheduled_kernels(backend): model.apply(...)`."""
 
-    def __init__(self, backend, interpret: bool = True):
+    def __init__(self, backend, interpret: bool | None = None):
+        if interpret is None:
+            interpret = pallas_interpret_mode()
         self._policy = ScheduledKernelPolicy(backend=backend, interpret=interpret)
 
     def __enter__(self):

@@ -10,6 +10,8 @@ step — no intermediate int32 tensor ever reaches HBM.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
@@ -25,18 +27,13 @@ def scheduled_qgemm(
     """int8[m,k] @ int8[k,n] (+ int32 bias) -> requantize -> clip -> int8."""
     if cfg.requant_scale is None:
         raise ValueError("quantized GEMM requires cfg.requant_scale")
-    cfg = GemmKernelConfig(
-        block_m=cfg.block_m,
-        block_k=cfg.block_k,
-        block_n=cfg.block_n,
-        dataflow=cfg.dataflow,
+    cfg = dataclasses.replace(
+        cfg,
         acc_dtype="int32",
         out_dtype=cfg.out_dtype or "int8",
-        requant_scale=cfg.requant_scale,
         clip_lo=cfg.clip_lo if cfg.clip_lo is not None else -128.0,
         clip_hi=cfg.clip_hi if cfg.clip_hi is not None else 127.0,
         activation=None,
         has_bias=bias is not None,
-        interpret=cfg.interpret,
     )
     return scheduled_gemm(x_q, w_q, cfg, bias)

@@ -244,6 +244,9 @@ def main():
         raise SystemExit("--requests must be >= 1")
     if args.batch < 1:
         raise SystemExit("--batch must be >= 1")
+    from repro.launch.compile_cache import use_persistent_compile_cache
+
+    use_persistent_compile_cache()
     if args.zoo:
         from repro.core.zoo import decode_model_names
 

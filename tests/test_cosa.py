@@ -113,3 +113,34 @@ def test_schedule_yaml_output():
     assert d["workload"]["N"] == 128
     assert len(d["levels"]) == GEMMINI.num_levels
     assert s.to_yaml()  # serializes
+
+
+@pytest.mark.parametrize(
+    "n,c,k,in_bytes,out_bytes",
+    [
+        (4096, 4096, 4096, 4, 4),
+        (4096, 4096, 4096, 2, 4),
+        (8192, 8192, 8192, 2, 2),
+        (4096, 8192, 4096, 1, 4),
+        (16, 640, 128, 1, 4),
+    ],
+)
+def test_tpu_schedules_fit_the_kernel_vmem_limit(n, c, k, in_bytes, out_bytes):
+    """What the Pallas GEMM holds in VMEM for a scheduled tile — every block
+    double-buffered plus the 4-byte accumulator scratch — fits the
+    scheduler's budget, which fits inside the limit the kernel asks for."""
+    from repro.core.descriptions.tpu_v5e import MOSAIC_RESERVE_BYTES
+    from repro.core.mapping import MappingGenerator
+
+    desc = make_tpu_v5e_description()
+    wl = GemmWorkload(
+        N=n, C=c, K=k, in_bytes=in_bytes, w_bytes=in_bytes, out_bytes=out_bytes
+    )
+    sched = ExtendedCosaScheduler(desc.arch).schedule(wl).best
+    cfg = MappingGenerator(desc).to_kernel_config(sched, interpret=False)
+    bm, bk, bn = cfg.block_m, cfg.block_k, cfg.block_n
+    held = 2 * (bm * bk + bk * bn) * in_bytes + 2 * bm * bn * out_bytes
+    held += bm * bn * 4
+    budget = desc.arch.levels[desc.arch.buffered_levels()[0]].size_bytes
+    assert held <= budget
+    assert budget + MOSAIC_RESERVE_BYTES == cfg.vmem_limit_bytes

@@ -29,14 +29,56 @@ def _assert_outputs_match(got, want, context: str):
 
 
 def test_interpret_mode_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert pallas_interpret_mode() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert pallas_interpret_mode() is False
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
+    """Interpret mode follows JAX's backend alone: no environment switch
+    can put a TPU's kernels into interpret mode."""
     import jax
 
     assert pallas_interpret_mode() is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pallas_interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert pallas_interpret_mode() is True
+
+
+def test_tpu_target_runs_scheduled_kernel_without_use_pallas(monkeypatch, tmp_path):
+    """``Target("tpu_v5e")`` leaves ``use_pallas`` at its default, and every
+    accelerator step still runs the scheduled Pallas GEMM — never the jnp
+    oracle — and the manifest of a saved module says so."""
+    import json
+
+    from repro.core.artifact import save_module
+    from repro.kernels import gemm, ops, qgemm
+
+    calls = []
+
+    def spy(x, w, cfg, bias=None):
+        calls.append(cfg)
+        return gemm.scheduled_gemm(x, w, cfg, bias)
+
+    # the wrappers are jitted: drop traces cached by earlier tests so this
+    # run traces through the spy
+    ops.matmul.clear_cache()
+    ops.qmatmul.clear_cache()
+    monkeypatch.setattr(ops, "scheduled_gemm", spy)
+    monkeypatch.setattr(qgemm, "scheduled_gemm", spy)
+    model = zoo.get_model("toycar_mlp")
+    module = repro.compile("toycar_mlp", Target("tpu_v5e", cache=False))
+    assert not module.backend.use_pallas
+    feeds = model.feeds(seed=2)
+    for g, w in zip(module.run(feeds), ir.execute_graph(model.build(), feeds)):
+        _assert_outputs_match(g, w, "toycar_mlp/tpu_v5e")
+    ops.matmul.clear_cache()
+    ops.qmatmul.clear_cache()
+
+    step_cfgs = {op.executor.kernel_config for op in module.ops.values()}
+    assert len(module.ops) == model.n_gemms
+    # steps that share a config and shape share one trace
+    assert calls and len(set(calls)) == len(step_cfgs)
+    assert all(cfg.interpret is pallas_interpret_mode() for cfg in calls)
+    save_module(module, tmp_path / "toycar")
+    manifest = json.loads((tmp_path / "toycar" / "manifest.json").read_text())
+    assert manifest["use_pallas"] is True
+    assert len(manifest["kernel_configs"]) == model.n_gemms
 
 
 # -- kernel dispatch parity: pallas vs emulated, across the zoo ---------------
@@ -53,7 +95,7 @@ def test_zoo_pallas_matches_emulated(name, mode):
     feeds = model.feeds(seed=3)
     for acc in model.accelerators:
         if acc.startswith("tpu"):
-            continue  # tpu desc takes the pallas path in both compiles
+            continue  # tpu desc runs the pallas kernel whatever use_pallas says
         emulated = repro.compile(
             model.build(), Target(acc, mode=mode, cache=False)
         ).run(feeds)

@@ -33,6 +33,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.executor import CompiledModule, FeedError
 
 
@@ -250,8 +251,11 @@ class BatchedModule:
                 continue
             bucket = pick_bucket(self._buckets, size)
             chunk = feeds_list[i : i + size]
-            outs = self.modules[bucket].run(self._pack(chunk, bucket))
-            results.extend(self._unpack(outs, len(chunk)))
+            with trace.span("repro.batch.pack"):
+                packed = self._pack(chunk, bucket)
+            outs = self.modules[bucket].run(packed)
+            with trace.span("repro.batch.unpack"):
+                results.extend(self._unpack(outs, len(chunk)))
             i += size
         return results
 

@@ -16,6 +16,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.accel import AcceleratorDescription
 from repro.core.collective import collective_cycles, collective_fn
 from repro.core.ir import (
@@ -218,6 +219,15 @@ class _CallState:
 #: sentinel pushed into the arena-handoff queue to stop the host-lane worker
 _STOP = object()
 
+#: the span of one plan execution on one feed set
+EXECUTE_SPAN = "repro.plan.execute"
+
+
+def step_span(step: PlanStep) -> str:
+    """The span of one plan step: ``repro.<lane>.<op>``."""
+    lane = "accel" if step.lane == "accel" else "host"
+    return f"repro.{lane}.{step.op}"
+
 
 @dataclass
 class ExecutionPlan:
@@ -249,11 +259,19 @@ class ExecutionPlan:
         # flat (slot, fn, arg_slots) triples: the hot loop avoids dataclass
         # attribute lookups entirely.
         self._fast_steps = tuple((s.slot, s.fn, s.arg_slots) for s in self.steps)
+        # the same steps, each inside its span (``repro.host.<op>`` or
+        # ``repro.accel.<op>``), run instead while a profiler records: the
+        # untraced loop pays one check per execution and nothing per step
+        traced_fns = [trace.spanned(step_span(s), s.fn) for s in self.steps]
+        self._traced_steps = tuple(
+            (s.slot, fn, s.arg_slots) for s, fn in zip(self.steps, traced_fns)
+        )
         # stage assignment: split steps into the two lanes, preserving topo
         # order within each, and compute per-step cross-lane watermarks.
         producer: dict[int, tuple[str, int]] = {}  # slot -> (lane, ordinal)
         lanes: dict[str, list] = {"host": [], "accel": []}
-        for s in self.steps:
+        traced_lanes: dict[str, list] = {"host": [], "accel": []}
+        for s, traced_fn in zip(self.steps, traced_fns):
             lane = s.lane if s.lane in lanes else "host"
             other = "accel" if lane == "host" else "host"
             need = 0
@@ -263,7 +281,9 @@ class ExecutionPlan:
                     need = max(need, p[1] + 1)
             producer[s.slot] = (lane, len(lanes[lane]))
             lanes[lane].append((s.slot, s.fn, s.arg_slots, need))
+            traced_lanes[lane].append((s.slot, traced_fn, s.arg_slots, need))
         self._lane_steps = {k: tuple(v) for k, v in lanes.items()}
+        self._traced_lane_steps = {k: tuple(v) for k, v in traced_lanes.items()}
 
     def new_arena(self) -> list:
         arena: list = [None] * self.n_slots
@@ -272,12 +292,18 @@ class ExecutionPlan:
         return arena
 
     def execute(self, feeds: dict[str, np.ndarray], arena: list) -> list[np.ndarray]:
+        if trace.enabled():
+            with trace.span(EXECUTE_SPAN):
+                return self._execute(feeds, arena, self._traced_steps)
+        return self._execute(feeds, arena, self._fast_steps)
+
+    def _execute(self, feeds, arena: list, steps: tuple) -> list[np.ndarray]:
         for name, slot in self.input_slots:
             try:
                 arena[slot] = np.asarray(feeds[name])
             except KeyError:
                 raise KeyError(f"missing feed for input {name!r}") from None
-        for slot, fn, arg_slots in self._fast_steps:
+        for slot, fn, arg_slots in steps:
             arena[slot] = fn(*[arena[i] for i in arg_slots])
         return [arena[i] for i in self.output_slots]
 
@@ -315,7 +341,8 @@ class ExecutionPlan:
         other = "accel" if lane == "host" else "host"
         run = state.run
         cond, done = run.cond, state.done
-        for slot, fn, arg_slots, need in self._lane_steps[lane]:
+        steps = self._traced_lane_steps if trace.enabled() else self._lane_steps
+        for slot, fn, arg_slots, need in steps[lane]:
             if need and done[other] < need:
                 with cond:
                     while done[other] < need and not run.aborted:
@@ -589,8 +616,9 @@ class CompiledModule:
                     if item is _STOP:
                         break  # worker died; its exception re-raised below
                     arena, state = item
-                    plan.execute_lane(arena, state, "accel")
-                    plan.wait_lane(state, "host")
+                    with trace.span(EXECUTE_SPAN):
+                        plan.execute_lane(arena, state, "accel")
+                        plan.wait_lane(state, "host")
                     results.append([arena[i] for i in plan.output_slots])
                     free.put(arena)
             except _LaneFailure:

@@ -35,8 +35,11 @@ from __future__ import annotations
 import threading
 from typing import Callable
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.core.accel import AcceleratorDescription
 from repro.core.intrinsics import HardwareIntrinsicGenerator
 from repro.core.ir import Node, gelu_ref, max_pool2d_ref
@@ -143,8 +146,6 @@ def pallas_interpret_mode() -> bool:
     XLA-on-host, so CPU CI covers the exact tiling the cycle model priced;
     on a TPU host the kernels compile through Mosaic.
     """
-    import jax
-
     return jax.default_backend() != "tpu"
 
 
@@ -171,6 +172,31 @@ def _im2col(x: np.ndarray, kh: int, kw: int, stride: int, padding: int) -> np.nd
                 cols[idx] = patch.reshape(-1)
                 idx += 1
     return cols
+
+
+def _to_device(a, traced: bool):
+    """``a`` on the device: a host array is uploaded and counted (inside a
+    ``repro.h2d`` span when ``traced``); a ``jax.Array`` moves nothing."""
+    if isinstance(a, jax.Array):
+        return a
+    a = np.asarray(a)
+    trace.count_h2d(a.nbytes)
+    if traced:
+        with trace.TraceAnnotation("repro.h2d", bytes=a.nbytes):
+            return jnp.asarray(a)
+    return jnp.asarray(a)
+
+
+def _to_host(a, traced: bool) -> np.ndarray:
+    """A kernel's result synced back to the host, counted (inside a
+    ``repro.d2h`` span when ``traced``)."""
+    if traced:
+        with trace.TraceAnnotation("repro.d2h", bytes=a.nbytes):
+            out = np.asarray(a)
+    else:
+        out = np.asarray(a)
+    trace.count_d2h(out.nbytes)
+    return out
 
 
 def _make_gemmini_executor(
@@ -436,8 +462,6 @@ def _make_pallas_executor(
     int64-accumulate-then-cast, so unfused naive-mode int GEMMs stay
     bit-exact.
     """
-    import jax.numpy as jnp
-
     from repro.kernels import ops as kops
 
     attrs = node.attrs
@@ -471,27 +495,36 @@ def _make_pallas_executor(
         def _finish(out):
             return out.reshape(out_shape).astype(out_dtype)
 
+    def _launch(on, x_j, w_j, b_j):
+        trace.count_launch()
+        if on:
+            with trace.TraceAnnotation("repro.launch"):
+                return _run2d(x_j, w_j, b_j)
+        return _run2d(x_j, w_j, b_j)
+
     def pallas_exec(x, w, bias=None, residual=None):
-        b_j = jnp.asarray(bias) if bias is not None else None
+        on = trace.enabled()
+        b_j = _to_device(bias, on) if bias is not None else None
         if is_conv:
             w = np.asarray(w)
             kh, kw, ci, co = w.shape
             x2 = _im2col(np.asarray(x), kh, kw, stride, padding)
-            out = _run2d(jnp.asarray(x2), jnp.asarray(w.reshape(kh * kw * ci, co)), b_j)
+            w2 = w.reshape(kh * kw * ci, co)
+            out = _launch(on, _to_device(x2, on), _to_device(w2, on), b_j)
         elif is_bmm:
-            x_j = jnp.asarray(x)
-            w_j = jnp.asarray(w)
+            x_j = _to_device(x, on)
+            w_j = _to_device(w, on)
             if transpose_b:
                 w_j = w_j.swapaxes(-2, -1)
             out = jnp.stack(
-                [_run2d(x_j[i], w_j[i], b_j) for i in range(x_j.shape[0])]
+                [_launch(on, x_j[i], w_j[i], b_j) for i in range(x_j.shape[0])]
             )
         else:
-            w_j = jnp.asarray(w)
+            w_j = _to_device(w, on)
             if transpose_b:
                 w_j = w_j.T
-            out = _run2d(jnp.asarray(x), w_j, b_j)
-        out = _finish(np.asarray(out))
+            out = _launch(on, _to_device(x, on), w_j, b_j)
+        out = _finish(_to_host(out, on))
         if residual is not None:
             out = out + residual
         return out

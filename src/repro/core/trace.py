@@ -1,0 +1,92 @@
+"""Program spans and transfer counters.
+
+Spans are ``jax.profiler.TraceAnnotation``s.  Under a profiler
+(``jax.profiler.trace``) they land in the profiler's own trace, on the
+clock of the device's op line, so a reduction of the trace can put device
+idle time on the program step that was running.  With no profiler running,
+``span`` constructs nothing and costs one ``is_enabled()`` check.
+
+Counters are process-wide integers (``COUNTERS``), exact when several
+threads run plans at once.  ``snapshot()`` returns them; read a stretch of
+work as the difference of two snapshots (``since``), as with a Prometheus
+counter.
+
+Span names (docs/architecture.md, "Observability"):
+
+* ``repro.serve.collect`` / ``repro.serve.dispatch`` — the MicroBatcher
+  worker gathering a batch / running it;
+* ``repro.batch.pack`` / ``repro.batch.unpack`` — BatchedModule packing
+  and padding a bucket, and slicing its rows back out;
+* ``repro.plan.execute`` — one ExecutionPlan execution on one feed set;
+* ``repro.host.<op>`` / ``repro.accel.<op>`` — one plan step of that lane;
+* ``repro.h2d`` / ``repro.launch`` / ``repro.d2h`` — inside a Pallas
+  step: one upload of a host array, one kernel enqueue, one result sync.
+  ``repro.h2d`` and ``repro.d2h`` carry the bytes moved as a ``bytes`` stat.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import threading
+
+from jax.profiler import TraceAnnotation
+
+#: the counters ``snapshot`` returns
+COUNTERS = ("h2d_transfers", "h2d_bytes", "d2h_syncs", "d2h_bytes", "kernel_launches")
+
+_NULL = contextlib.nullcontext()
+_lock = threading.Lock()
+_counts = dict.fromkeys(COUNTERS, 0)
+
+
+def enabled() -> bool:
+    """Whether a profiler is recording spans."""
+    return TraceAnnotation.is_enabled()
+
+
+def span(name: str):
+    """A span named ``name`` while a profiler runs; a shared no-op context
+    otherwise."""
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name)
+    return _NULL
+
+
+def spanned(name: str, fn):
+    """``fn`` inside an unconditional span: for paths taken only once
+    ``enabled()`` was checked."""
+
+    def run(*args):
+        with TraceAnnotation(name):
+            return fn(*args)
+
+    return run
+
+
+def count_h2d(nbytes: int) -> None:
+    with _lock:
+        _counts["h2d_transfers"] += 1
+        _counts["h2d_bytes"] += nbytes
+
+
+def count_d2h(nbytes: int) -> None:
+    with _lock:
+        _counts["d2h_syncs"] += 1
+        _counts["d2h_bytes"] += nbytes
+
+
+def count_launch() -> None:
+    with _lock:
+        _counts["kernel_launches"] += 1
+
+
+def snapshot() -> dict[str, int]:
+    """Every counter's value since the process started."""
+    with _lock:
+        return dict(_counts)
+
+
+def since(before: dict[str, int]) -> dict[str, int]:
+    """What each counter has counted since the snapshot ``before``."""
+    now = snapshot()
+    return {k: now[k] - before.get(k, 0) for k in COUNTERS}

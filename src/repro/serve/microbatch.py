@@ -23,6 +23,8 @@ from collections import deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
 
+from repro.core import trace
+
 
 @dataclass
 class BatchStats:
@@ -119,7 +121,8 @@ class MicroBatcher:
 
     def _dispatch_loop(self) -> None:
         while True:
-            batch = self._collect()
+            with trace.span("repro.serve.collect"):
+                batch = self._collect()
             if batch is None:
                 return
             # transition every future to RUNNING; a client that cancelled
@@ -128,28 +131,32 @@ class MicroBatcher:
             batch = [
                 item for item in batch if item[1].set_running_or_notify_cancel()
             ]
-            if not batch:
-                continue
-            feeds_list = [feeds for feeds, _, _ in batch]
-            try:
-                outs = self.module.run_many(feeds_list)
-            except BaseException:  # noqa: BLE001 — isolate the bad request
-                # one request's bad feeds (or any input-dependent failure)
-                # must not fail its co-batched neighbors: re-run each
-                # request alone and attribute errors individually
-                for feeds, future, _ in batch:
-                    try:
-                        out = self.module.run_many([feeds])[0]
-                    except BaseException as e:  # noqa: BLE001
-                        future.set_exception(e)
-                    else:
-                        self.stats.requests += 1
-                        self.stats.batches += 1
-                        self.stats.batch_sizes.append(1)
-                        future.set_result(out)
-                continue
-            self.stats.requests += len(batch)
-            self.stats.batches += 1
-            self.stats.batch_sizes.append(len(batch))
-            for (_, future, _), out in zip(batch, outs):
-                future.set_result(out)
+            if batch:
+                with trace.span("repro.serve.dispatch"):
+                    self._dispatch(batch)
+
+    def _dispatch(self, batch: list) -> None:
+        """Run one batch as one ``run_many`` and resolve its futures."""
+        feeds_list = [feeds for feeds, _, _ in batch]
+        try:
+            outs = self.module.run_many(feeds_list)
+        except BaseException:  # noqa: BLE001 — isolate the bad request
+            # one request's bad feeds (or any input-dependent failure)
+            # must not fail its co-batched neighbors: re-run each
+            # request alone and attribute errors individually
+            for feeds, future, _ in batch:
+                try:
+                    out = self.module.run_many([feeds])[0]
+                except BaseException as e:  # noqa: BLE001
+                    future.set_exception(e)
+                else:
+                    self.stats.requests += 1
+                    self.stats.batches += 1
+                    self.stats.batch_sizes.append(1)
+                    future.set_result(out)
+            return
+        self.stats.requests += len(batch)
+        self.stats.batches += 1
+        self.stats.batch_sizes.append(len(batch))
+        for (_, future, _), out in zip(batch, outs):
+            future.set_result(out)

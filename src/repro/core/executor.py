@@ -365,23 +365,104 @@ class ExecutionPlan:
                 raise _LaneFailure()
 
 
+def _attn_epilogues(
+    graph: Graph, order: list[Node], ops: dict[Node, CompiledOp]
+) -> dict[Node, tuple[Callable, Node, tuple[Node, ...]]]:
+    """The attention-score epilogues the plan runs on the device.
+
+    A scores GEMM whose executor offers ``fuse_attn_epilogue`` (a Pallas
+    step, see ``lowering._attn_scores_step``) followed by the sole-consumer
+    chain ``dequantize -> [add(const mask)] -> softmax(last axis) ->
+    quantize(int8)``, none of it a graph output, becomes one step.  Maps
+    the GEMM to (its fused step fn, the chain's last node, the chain)."""
+    consumers: dict[Node, list[Node]] = {}
+    for n in order:
+        for i in n.inputs:
+            if i is not None:
+                consumers.setdefault(i, []).append(n)
+    outputs = set(graph.outputs)
+
+    def sole(n: Node, op: str) -> Node | None:
+        cs = consumers.get(n, ())
+        if len(cs) == 1 and n not in outputs and cs[0].op == op:
+            return cs[0]
+        return None
+
+    residents: dict = {}  # one device copy per mask constant
+    fused = {}
+    for n in order:
+        fuse = getattr(ops[n].executor, "fuse_attn_epilogue", None) if n in ops else None
+        deq = sole(n, "dequantize") if fuse is not None else None
+        if deq is None:
+            continue
+        add = sole(deq, "add")
+        mask = None
+        if add is not None:
+            mask = add.inputs[1] if add.inputs[0] is deq else add.inputs[0]
+            if not (
+                mask.is_const()
+                and mask.dtype == add.dtype == "float32"
+                and np.broadcast_shapes(mask.shape, n.shape) == tuple(n.shape)
+            ):
+                continue
+        softmax = sole(add or deq, "softmax")
+        if softmax is None or softmax.dtype != "float32" or softmax.attrs.get(
+            "axis", -1
+        ) not in (-1, len(n.shape) - 1):
+            continue
+        quant = sole(softmax, "quantize")
+        if quant is None or quant.dtype != "int8":
+            continue
+        fn = fuse(
+            scale=deq.attrs["scale"],
+            probs_scale=quant.attrs["scale"],
+            mask=None if mask is None else mask.value,
+            mask_first=add is not None and add.inputs[0] is mask,
+            host_ops=(
+                compile_host_op(deq),
+                None if add is None else compile_host_op(add),
+                compile_host_op(softmax),
+                compile_host_op(quant),
+            ),
+            residents=residents,
+        )
+        if fn is not None:
+            chain = tuple(c for c in (deq, add, softmax, quant) if c is not None)
+            fused[n] = (fn, quant, chain)
+    return fused
+
+
 def build_plan(graph: Graph, ops: dict[Node, CompiledOp]) -> ExecutionPlan:
-    """Lower a compiled graph to its execution plan (one toposort, ever)."""
+    """Lower a compiled graph to its execution plan (one toposort, ever).
+
+    An attention-score epilogue after a Pallas scores GEMM
+    (``_attn_epilogues``) becomes one ``attn_scores`` step that writes the
+    chain's int8 result; the chain's host steps are not planned."""
     order = graph.toposort()
     slot_of: dict[Node, int] = {n: i + 1 for i, n in enumerate(order)}
     input_slots: list[tuple[str, int]] = []
     const_slots: list[tuple[int, np.ndarray]] = []
     steps: list[PlanStep] = []
+    fused = _attn_epilogues(graph, order, ops)
+    absorbed = {c for _, _, chain in fused.values() for c in chain}
     for n in order:
         slot = slot_of[n]
         if n.op == "input":
             input_slots.append((n.name, slot))
         elif n.op == "const":
             const_slots.append((slot, n.value))
+        elif n in absorbed:
+            continue
         else:
             arg_slots = tuple(
                 _NONE_SLOT if i is None else slot_of[i] for i in n.inputs
             )
+            if n in fused:
+                fn, last, _ = fused[n]
+                steps.append(
+                    PlanStep(slot_of[last], fn, arg_slots, "attn_scores", n.name, "accel")
+                )
+                continue
             if n in ops:
                 fn = ops[n].executor
                 # accelerator executors may offer plan-time specialization

@@ -32,6 +32,8 @@ Epilogue attribute contract on generalized ops (set by the passes):
 
 from __future__ import annotations
 
+import functools
+import math
 import threading
 from typing import Callable
 
@@ -502,29 +504,31 @@ def _make_pallas_executor(
                 return _run2d(x_j, w_j, b_j)
         return _run2d(x_j, w_j, b_j)
 
-    def pallas_exec(x, w, bias=None, residual=None):
-        on = trace.enabled()
-        b_j = _to_device(bias, on) if bias is not None else None
+    def _device_out(on, x, w, b_j):
+        """The kernel's result, left on the device."""
         if is_conv:
             w = np.asarray(w)
             kh, kw, ci, co = w.shape
             x2 = _im2col(np.asarray(x), kh, kw, stride, padding)
             w2 = w.reshape(kh * kw * ci, co)
-            out = _launch(on, _to_device(x2, on), _to_device(w2, on), b_j)
-        elif is_bmm:
+            return _launch(on, _to_device(x2, on), _to_device(w2, on), b_j)
+        if is_bmm:
             x_j = _to_device(x, on)
             w_j = _to_device(w, on)
             if transpose_b:
                 w_j = w_j.swapaxes(-2, -1)
-            out = jnp.stack(
+            return jnp.stack(
                 [_launch(on, x_j[i], w_j[i], b_j) for i in range(x_j.shape[0])]
             )
-        else:
-            w_j = _to_device(w, on)
-            if transpose_b:
-                w_j = w_j.T
-            out = _launch(on, _to_device(x, on), w_j, b_j)
-        out = _finish(_to_host(out, on))
+        w_j = _to_device(w, on)
+        if transpose_b:
+            w_j = w_j.T
+        return _launch(on, _to_device(x, on), w_j, b_j)
+
+    def pallas_exec(x, w, bias=None, residual=None):
+        on = trace.enabled()
+        b_j = _to_device(bias, on) if bias is not None else None
+        out = _finish(_to_host(_device_out(on, x, w, b_j), on))
         if residual is not None:
             out = out + residual
         return out
@@ -533,4 +537,179 @@ def _make_pallas_executor(
     # (x, w, bias) that ``jax.jit(...).lower`` can compile ahead of time
     pallas_exec.kernel_config = cfg
     pallas_exec.run_kernel = _run2d
+    if _attn_scores_exact(node):
+        pallas_exec.fuse_attn_epilogue = functools.partial(
+            _attn_scores_step, node, lambda on, x, w: _device_out(on, x, w, None)
+        )
     return pallas_exec
+
+
+# ---------------------------------------------------------------------------
+# the attention-score epilogue on the device
+# ---------------------------------------------------------------------------
+
+#: Rounding guard of the device attention epilogue.  The host chain defines
+#: the result: ``x = fl32(s * scale + mask)``, ``p = fl32(softmax_f64(x))``,
+#: ``out = clip(round_half_even(fl32(p / probs_scale)), -128, 127)``.  The
+#: device computes the same float32 ``x``: ``s -> float32`` is exact for
+#: ``|s| <= 2^24`` (int8 operands, K <= 1024), ``s * scale`` is exact for a
+#: power-of-two scale, and the mask add is the same one IEEE float32 add.
+#: From ``x`` on, with ``u = 2^-24``, the device's ``q = p / probs_scale``
+#: differs from the exact one by a relative error of at most
+#:
+#: * ``88u`` — ``x - max(x)`` rounded to float32 (``|x - m| <= 88`` wherever
+#:   ``exp(x - m)`` is a normal float32; below that both paths' ``e`` lie
+#:   under 2^-126 and move ``q`` by less than 2^-100);
+#: * ``256u`` — ``exp``: the allowance for the device's own, which is not
+#:   correctly rounded (a TPU v5e's errs by at most 112u over every float32
+#:   in ``[-88, -2^-12]``, PERF.md);
+#: * ``(n - 1)u`` — the float32 sum of a row of ``n``, in any order;
+#: * ``64u`` — the division by the sum, and by ``probs_scale`` (a TPU
+#:   v5e's sum and division together read at most 9.3u on sampled rows,
+#:   PERF.md);
+#:
+#: and the host's ``q`` from the exact one by ``u`` (float64 to float32,
+#: then the division).  So ``|q_dev - q_host| <= (n + 408)u * q``.  A row in
+#: which every ``q_dev`` lies at least ``ATTN_TOL * max(q_dev, 1)`` from
+#: each ``k + 1/2`` rounds exactly as the host's does whenever
+#: ``(n + 408)u * (1 + 2^-10) < ATTN_TOL``: with ``ATTN_TOL = 2^-13 = 2048u``
+#: that holds for rows of up to ``ATTN_MAX_ROW = 1536`` elements.  Every
+#: other row (and every row with a non-finite ``q``) is flagged and
+#: recomputed by the host chain itself.  2^-14 was the first choice; the
+#: largest error of ``q`` read on a TPU v5e, 4.5e-6 (75u), was not under
+#: its sixteenth.
+ATTN_TOL = 2.0**-13
+ATTN_MAX_ROW = 1536
+#: at most this share of a step's rows comes back through the compact
+#: gather of flagged rows; a step that flags more syncs its whole scores
+_ATTN_GATHER_SHARE = 16
+
+
+def _attn_scores_exact(node: Node) -> bool:
+    """Whether ``node`` is a scores GEMM whose float32 epilogue on the device
+    starts from the host's exact ``x`` (see ``ATTN_TOL``): an int32 dense of
+    two int8 operands, no bias or residual, K <= 1024, rows of at most
+    ``ATTN_MAX_ROW``."""
+    return (
+        node.op in ("dense", "generalized_dense")
+        and node.dtype == "int32"
+        and all(i is None for i in node.inputs[2:])
+        and all(i.dtype == "int8" for i in node.inputs[:2])
+        and node.inputs[0].shape[-1] <= 1024
+        and node.shape[-1] <= ATTN_MAX_ROW
+    )
+
+
+def _power_of_two(scale: float) -> bool:
+    return scale > 0 and math.frexp(scale)[0] == 0.5
+
+
+def _attn_q(s, mask, scale: float, probs_scale: float):
+    """``p / probs_scale`` of the scores ``s``, in float32, in the host
+    chain's order."""
+    x = s.astype(jnp.float32) * jnp.float32(scale)
+    if mask is not None:
+        x = x + mask
+    e = jnp.exp(x - jnp.max(x, axis=-1, keepdims=True))
+    p = e / jnp.sum(e, axis=-1, keepdims=True)
+    return p / jnp.float32(probs_scale)
+
+
+def _attn_out(q, s, capacity: int):
+    """The int8 result, the per-row flags of the rounding guard, and the
+    scores of the first ``capacity`` flagged rows."""
+    out = jnp.clip(jnp.round(q), -128, 127).astype(jnp.int8)
+    near = jnp.abs(jnp.abs(q - jnp.floor(q)) - 0.5) < ATTN_TOL * jnp.maximum(q, 1.0)
+    flags = jnp.any(near | ~jnp.isfinite(q), axis=-1)
+    rows = s.reshape(-1, s.shape[-1])
+    (idx,) = jnp.nonzero(flags.reshape(-1), size=capacity, fill_value=0)
+    return out, flags, rows[idx]
+
+
+@functools.partial(
+    jax.jit, static_argnames=("shape", "scale", "probs_scale", "capacity")
+)
+def _attn_epilogue(s, mask, *, shape, scale, probs_scale, capacity):
+    s = s.reshape(shape)
+    return _attn_out(_attn_q(s, mask, scale, probs_scale), s, capacity)
+
+
+class _Resident:
+    """A constant's device copy, uploaded by the first call that needs it."""
+
+    def __init__(self, value: np.ndarray):
+        self.value = value
+        self._dev = None
+        self._lock = threading.Lock()
+
+    def get(self, on: bool):
+        if self._dev is None:
+            with self._lock:
+                if self._dev is None:
+                    self._dev = _to_device(self.value, on)
+        return self._dev
+
+
+def _attn_scores_step(
+    node: Node,
+    device_scores: Callable,
+    *,
+    scale: float,
+    probs_scale: float,
+    mask: np.ndarray | None,
+    mask_first: bool,
+    host_ops: tuple,
+    residents: dict,
+) -> Callable | None:
+    """One accelerator step for ``quantize(softmax(dequantize(s) [+ mask]))``
+    over the scores GEMM ``node``: the kernels as the plain step runs them,
+    then ``_attn_epilogue`` on the device; only the int8 result and the
+    row flags are synced.  Flagged rows are recomputed by ``host_ops``
+    (dequantize, add or None, softmax, quantize: the plan's own closures
+    for those nodes) on those rows' scores.  None where the scale is not a
+    power of two, so ``ATTN_TOL``'s argument does not hold."""
+    if not _power_of_two(scale):
+        return None
+    dequantize, add, softmax, quantize = host_ops
+    shape = tuple(node.shape)
+    n = shape[-1]
+    n_rows = math.prod(shape[:-1])
+    capacity = -(-n_rows // _ATTN_GATHER_SHARE)
+    resident = None
+    if mask is not None:
+        resident = residents.setdefault(id(mask), _Resident(mask))
+    row_shape = (1,) * (len(shape) - 2) + (n,)
+
+    def host_rows(s_rows, idx):
+        """The host chain over the rows ``idx`` (flat row numbers)."""
+        shaped = (len(idx),) + row_shape
+        x = dequantize(s_rows.reshape(shaped))
+        if add is not None:
+            lead = np.unravel_index(idx, shape[:-1])
+            m_rows = np.broadcast_to(mask, shape)[lead].reshape(shaped)
+            x = add(m_rows, x) if mask_first else add(x, m_rows)
+        return quantize(softmax(x))
+
+    def attn_scores(x, w, bias=None, residual=None):
+        on = trace.enabled()
+        s = device_scores(on, x, w)
+        m = resident.get(on) if resident is not None else None
+        out_d, flags_d, picked_d = _attn_epilogue(
+            s, m, shape=shape, scale=scale, probs_scale=probs_scale,
+            capacity=capacity,
+        )
+        out = _to_host(out_d, on)
+        idx = np.flatnonzero(_to_host(flags_d, on))
+        trace.count_attn_rows(n_rows, len(idx))
+        if not len(idx):
+            return out
+        if len(idx) <= capacity:
+            s_rows = _to_host(picked_d, on)[: len(idx)]
+        else:
+            s_rows = _to_host(s, on).reshape(n_rows, n)[idx]
+        with trace.span("repro.host.softmax_fallback"):
+            out = out.reshape(n_rows, n).copy()
+            out[idx] = host_rows(s_rows, idx).reshape(len(idx), n)
+            return out.reshape(shape)
+
+    return attn_scores

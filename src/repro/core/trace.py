@@ -21,7 +21,16 @@ Span names (docs/architecture.md, "Observability"):
 * ``repro.host.<op>`` / ``repro.accel.<op>`` — one plan step of that lane;
 * ``repro.h2d`` / ``repro.launch`` / ``repro.d2h`` — inside a Pallas
   step: one upload of a host array, one kernel enqueue, one result sync.
-  ``repro.h2d`` and ``repro.d2h`` carry the bytes moved as a ``bytes`` stat.
+  ``repro.h2d`` and ``repro.d2h`` carry the bytes moved as a ``bytes`` stat;
+* ``repro.accel.attn_scores`` — the step that runs an attention-score
+  epilogue (dequantize, mask, softmax, quantize) on the device after its
+  scores GEMM; ``repro.host.softmax_fallback`` inside it — the host chain
+  recomputing the rows its rounding guard flagged.
+
+Counters: ``h2d_transfers`` / ``h2d_bytes``, ``d2h_syncs`` / ``d2h_bytes``,
+``kernel_launches``; ``attn_epilogue_rows`` — rows of scores the device
+epilogue ran over; ``attn_fallback_rows`` — those of them the host chain
+recomputed.
 """
 
 from __future__ import annotations
@@ -32,7 +41,15 @@ import threading
 from jax.profiler import TraceAnnotation
 
 #: the counters ``snapshot`` returns
-COUNTERS = ("h2d_transfers", "h2d_bytes", "d2h_syncs", "d2h_bytes", "kernel_launches")
+COUNTERS = (
+    "h2d_transfers",
+    "h2d_bytes",
+    "d2h_syncs",
+    "d2h_bytes",
+    "kernel_launches",
+    "attn_epilogue_rows",
+    "attn_fallback_rows",
+)
 
 _NULL = contextlib.nullcontext()
 _lock = threading.Lock()
@@ -78,6 +95,12 @@ def count_d2h(nbytes: int) -> None:
 def count_launch() -> None:
     with _lock:
         _counts["kernel_launches"] += 1
+
+
+def count_attn_rows(rows: int, fallback: int) -> None:
+    with _lock:
+        _counts["attn_epilogue_rows"] += rows
+        _counts["attn_fallback_rows"] += fallback
 
 
 def snapshot() -> dict[str, int]:

@@ -4,8 +4,9 @@ Nothing runs here: each test lowers a kernel for a v5e device that JAX
 describes but does not attach, and the TPU compiler (Mosaic included)
 accepts it or raises what the chip's compiler would raise — a block that
 does not tile, or more VMEM than the kernel asked for.  That covers ToyCar's
-accelerator steps, as a real ``tpu_v5e`` compile binds them, and the
-scheduled GEMMs at widths where the scheduler's VMEM budget matters.
+accelerator steps, as a real ``tpu_v5e`` compile binds them, the
+scheduled GEMMs at widths where the scheduler's VMEM budget matters, and
+the device attention epilogue at MusicGen's width.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -149,3 +150,18 @@ def test_scheduled_gemm_at_width_compiles_for_v5e(
         _compile_for_chip(lambda x, w, b: kops.qmatmul(x, w, b, cfg), [x, w, bias])
     else:
         _compile_for_chip(lambda x, w: kops.matmul(x, w, cfg), [x, w])
+
+
+def test_attention_epilogue_at_prefill512_compiles_for_v5e(one_chip, no_compile_cache):
+    """The device attention epilogue of ``musicgen.prefill512``: 2 x 24 heads
+    of 512 x 512 int32 scores and the causal mask."""
+    shape = (48, 512, 512)
+    s = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct(shape[1:], jnp.float32, sharding=one_chip)
+    compiled = lowering._attn_epilogue.lower(
+        s, mask, shape=shape, scale=2.0**-9, probs_scale=2.0**-7,
+        capacity=48 * 512 // 16,
+    ).compile()
+    out, flags, rows = compiled.out_info
+    assert out.shape == shape and out.dtype == jnp.int8
+    assert flags.shape == shape[:-1] and rows.shape == (48 * 512 // 16, 512)

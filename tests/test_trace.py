@@ -76,6 +76,8 @@ def _per_execution(bucket: int) -> dict[str, int]:
         "d2h_syncs": len(layers),
         "d2h_bytes": sum(bucket * d_out for _, d_out in layers),
         "kernel_launches": len(layers),
+        "attn_epilogue_rows": 0,
+        "attn_fallback_rows": 0,
     }
 
 

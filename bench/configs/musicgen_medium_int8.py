@@ -26,6 +26,12 @@ def sample_shape(cfg: dict, traffic: dict) -> tuple[int, ...]:
     return (traffic["seq_len"], cfg["hidden_size"])
 
 
+def test_sizes(cfg: dict, traffic: dict) -> None:
+    """Cut to the sizes the CPU tests drive (in place)."""
+    cfg.update(hidden_size=64, num_attention_heads=4, ffn_dim=128, num_hidden_layers=2)
+    traffic.update(seq_len=16, samples_per_call=2, buckets=[2])
+
+
 def _dims(cfg):
     d, h = cfg["hidden_size"], cfg["num_attention_heads"]
     return d, h, d // h, cfg["ffn_dim"], cfg["num_hidden_layers"]

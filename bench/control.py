@@ -13,7 +13,11 @@ check's numbers of each run as one JSON line:
 * ``fault`` — the program with one element of one answer in every call
   altered where it is produced (it has to come out not correct);
 * ``half_batch`` — the program run over half of every call's samples, the
-  other half answered with that half's answers (not correct either).
+  other half answered with that half's answers (not correct either);
+* ``bf16_answers`` — the program with every float answer rounded to
+  bfloat16, a lower precision than a float32 answer: for configurations
+  with float answers, whose limits it has to fail (an int8 answer is
+  exact in bfloat16 and is left as it is).
 
 ``--forms`` picks which.  Needs the chip, as ``bench/run.py`` does.
 """
@@ -45,7 +49,9 @@ class ReferenceModule:
 
 class AlteredAnswers:
     """The compiled module with one element of the first answer of every
-    call changed: a wrong answer where it is produced."""
+    call changed: a wrong answer where it is produced.  An integer element
+    has its lowest bit flipped; a float one moves by more than the
+    answer's largest magnitude."""
 
     def __init__(self, module):
         self.module = module
@@ -53,9 +59,31 @@ class AlteredAnswers:
     def run_many(self, feeds_list):
         outs = self.module.run_many(feeds_list)
         first = outs[0][0].copy()
-        first.flat[0] = np.int8(int(first.flat[0]) ^ 1)
+        if np.issubdtype(first.dtype, np.integer):
+            first.flat[0] ^= 1
+        else:
+            first.flat[0] += 1 + np.abs(first).max()
         outs[0] = [first] + list(outs[0][1:])
         return outs
+
+
+class RoundedAnswers:
+    """The compiled module with every float answer rounded to bfloat16 and
+    back to its own type."""
+
+    def __init__(self, module):
+        self.module = module
+
+    def run_many(self, feeds_list):
+        import jax.numpy as jnp
+
+        def rounded(o):
+            o = np.asarray(o)
+            if not np.issubdtype(o.dtype, np.floating):
+                return o
+            return o.astype(jnp.bfloat16).astype(o.dtype)
+
+        return [[rounded(o) for o in outs] for outs in self.module.run_many(feeds_list)]
 
 
 class HalfBatch:
@@ -84,7 +112,12 @@ def half_batch(cell):
     return lambda module, params: HalfBatch(module)
 
 
-FORMS = {"program": None, "control": control, "fault": fault, "half_batch": half_batch}
+def bf16_answers(cell):
+    return lambda module, params: RoundedAnswers(module)
+
+
+FORMS = {"program": None, "control": control, "fault": fault, "half_batch": half_batch,
+         "bf16_answers": bf16_answers}
 
 
 def main(argv=None) -> int:

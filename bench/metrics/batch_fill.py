@@ -1,6 +1,6 @@
 """Serving: mean requests per dispatch over ``max_batch``, from
 ``MicroBatcher.stats``, in percent.  Open-loop cells only.  Moves
-``latency_p95_ms``."""
+``latency_p50_ms``."""
 
 
 def read(run):

@@ -1,7 +1,7 @@
 """Serving: the share of the traced window the MicroBatcher's worker spent
 dispatching — its ``repro.serve.dispatch`` spans over the window, in
 percent; near 100 the server is at or past its capacity.  Open-loop cells;
-moves ``latency_p95_ms``."""
+moves ``latency_p50_ms``."""
 
 from bench import program
 

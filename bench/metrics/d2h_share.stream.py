@@ -1,7 +1,7 @@
 """Plan: the share of plan execution time spent syncing results back to
 the host — the program's ``repro.d2h`` spans over its
 ``repro.plan.execute`` spans, in percent.  Open-loop cells; moves
-``latency_p95_ms``."""
+``latency_p50_ms``."""
 
 from bench import program
 

@@ -1,5 +1,5 @@
 """Device: idle share of the traced window in open-loop cells (profiler
-trace).  Moves ``latency_p95_ms``."""
+trace).  Moves ``latency_p50_ms``."""
 
 from bench.metrics._idle import idle_share
 

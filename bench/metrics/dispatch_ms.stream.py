@@ -1,6 +1,6 @@
 """Plan: mean host time of one ``run_many`` the MicroBatcher issued
 (bucket packing, every plan step, unpacking).  Open-loop cells; moves
-``latency_p95_ms``."""
+``latency_p50_ms``."""
 
 from bench.metrics._dispatch import mean_call_ms
 

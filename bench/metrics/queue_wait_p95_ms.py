@@ -1,7 +1,7 @@
 """Serving: 95th percentile over every request of the time from its due
 time to the start of the ``run_many`` that carried it (the harness wraps
 the module it hands to MicroBatcher).  Host clock; open-loop cells only.
-Moves ``latency_p95_ms``."""
+Moves ``latency_p50_ms``."""
 
 import numpy as np
 

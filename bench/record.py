@@ -42,6 +42,7 @@ class Run:
     queue_waits_s: np.ndarray | None = None  # open loop: dispatch start - due
     batches: tuple | None = None  # open loop: (requests, dispatches, max_batch)
     trace: object | None = None  # tracing.TraceSummary
+    counters: dict | None = None  # repro.core.trace counters: the window's counts
 
     def call_gemms(self, n: int) -> list[counting.Gemm]:
         return self.model.gemms(self.config, self.sample_shape, n)

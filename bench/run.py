@@ -15,7 +15,9 @@ name under ``bench/`` (see ``bench/spec.py``).  The run:
    will use, and draws the inputs;
 3. measures for ``--seconds`` (``--trace 1``: for at most
    ``TRACE_CAP_S``, under the profiler) — an open loop through
-   ``repro.serve.MicroBatcher``, or a closed loop of ``run_many``;
+   ``repro.serve.MicroBatcher``, or a closed loop of ``run_many`` — and
+   takes what the program's counters (``repro.core.trace``) counted over
+   it, read by the ``program_counter`` metrics in every run;
 4. reads the chip's peak memory, frees the program, and compares what the
    window answered with the plain reference (``bench/check.py``);
 5. prints the check's numbers as the last lines of standard error, and one
@@ -125,6 +127,36 @@ class GcPauses:
         return f"{len(self.pauses)} collections, longest {longest * 1e3:.3f} ms"
 
 
+#: seconds of due time per slice of the ``worker:`` line's latency profile
+SLICE_S = 5.0
+
+
+def _worker_line(res, offsets, latencies) -> str:
+    """The longest ``run_many`` and the longest gap between two, and the
+    95th-percentile latency over the window and per ``SLICE_S`` of due time:
+    where a backlog built up and how long it took to drain."""
+    import numpy as np
+
+    from bench.record import percentile
+
+    start, end = np.asarray(res.calls.start), np.asarray(res.calls.end)
+    if len(start) == 0:
+        return "worker: no run_many calls"
+    k = int(np.argmax(end - start))
+    gaps = start[1:] - end[:-1]
+    g = int(np.argmax(gaps)) if len(gaps) else 0
+    gap = f"{gaps[g] * 1e3:.3f} ms at {end[g] - res.t0:.3f} s" if len(gaps) else "none"
+    slices = (offsets // SLICE_S).astype(int)
+    p95 = " ".join(f"{percentile(latencies[slices == s], 95) * 1e3:.1f}"
+                   for s in range(int(slices.max()) + 1) if np.any(slices == s))
+    return (
+        f"worker: longest run_many {(end[k] - start[k]) * 1e3:.3f} ms at "
+        f"{start[k] - res.t0:.3f} s, longest gap between calls {gap}; latency p95 "
+        f"{percentile(latencies, 95) * 1e3:.3f} ms over the window, per {SLICE_S:g} s "
+        f"of due time (ms): {p95}"
+    )
+
+
 def measure(cell, seed: int, seconds: float, trace: bool, devices=None, wrap=None,
             t_start=None):
     """Set up, measure and check one run; returns the result dict and the
@@ -138,6 +170,8 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices=None, wrap=Non
     from bench import device, generator, tracing
     from bench.check import compare, reference_blocks
     from bench.record import Run
+    from bench.spec import metric_reader
+    from repro.core import trace as counters
 
     if devices is None:
         devices = device.require_tpu(cell.chips)
@@ -211,7 +245,9 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices=None, wrap=Non
     )
     if loop == "open":
         with tracing.capture(trace_dir) if trace else contextlib.nullcontext():
+            before = counters.snapshot()
             res = generator.drive_open(module, inputs, offsets, traffic, "x")
+            counted = counters.since(before)
         t_end = np.nanmax(res.done) if np.isfinite(res.done).any() else res.t0
         grace_end = res.t0 + offsets[-1] + generator.ANSWER_GRACE_S if len(offsets) else res.t0
         latencies = np.where(np.isnan(res.done), grace_end, res.done) - res.due
@@ -229,10 +265,13 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices=None, wrap=Non
             f"p99 {np.percentile(late, 99) * 1e3:.3f} ms, max {late.max() * 1e3:.3f} ms "
             f"at {offsets[late.argmax()]:.3f} s"
         )
+        _log(_worker_line(res, offsets, latencies))
         attempted, failed = len(offsets), len(offsets) - answered
     else:
         with tracing.capture(trace_dir) if trace else contextlib.nullcontext():
+            before = counters.snapshot()
             res = generator.drive_closed(module, pool, window, rng)
+            counted = counters.since(before)
         answered = sum(res.calls.samples)
         run = Run(
             loop, cfg, traffic, model, shape, peaks, setup_s, compile_s, warm_s,
@@ -242,25 +281,30 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices=None, wrap=Non
         failed = res.failed_samples
     compiles.active = False
     gc.unfreeze()
+    run.counters = counted
     n_calls = len(run.calls.start)
     _log(
         f"window: {run.window_s:.3f} s, {n_calls} run_many calls, {answered} samples "
         f"answered, {compiles.count} backend compiles inside the window; "
         f"gc: {gc_pauses.close()}"
     )
+    _log("counters: " + "; ".join(
+        f"{k} {v} ({v / max(n_calls, 1):.6g}/call, {v / max(answered, 1):.6g}/sample)"
+        for k, v in counted.items()
+    ))
     if trace:
         summary = tracing.reduce_trace(tracing.newest_xplane(trace_dir))
         run.trace = summary
+        kernels = ", ".join(f"{k} {s}" for k, s in summary.kernel_s.items())
         _log(
             f"trace: window {summary.window_s:.3f} s, device busy {summary.busy_s:.4f} s, "
-            f"GEMM kernels {summary.gemm_s:.4f} s, {summary.n_device_events} device events"
+            f"GEMM kernels {summary.gemm_s:.4f} s, {summary.n_device_events} device events; "
+            f"kernels (s): {kernels or 'none'}"
         )
 
     metrics = {}
     for m in cell.metrics(trace):
-        from bench.spec import metric_reader
-
-        value = metric_reader(m["name"])(run)
+        value = metric_reader(m["name"], cell.root)(run)
         if value is not None:
             metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
     dev_info["memory_peak_bytes"] = device.memory_peak_bytes(devices)

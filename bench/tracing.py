@@ -9,6 +9,9 @@ harness's own spans and JAX's dispatch events stay on the host lines).
   chip's op line, averaged over the chips;
 * ``gemm_s`` — the device time of the events classed as the scheduled
   GEMM kernel (``is_gemm_kernel``);
+* ``kernel_s`` — device time per Mosaic custom call, keyed by its
+  instruction's name without the numeric suffix (``%qmatmul``,
+  ``kernel_name``): a kernel's roofline share is one reader of it;
 * ``device_ops`` — the ten operations that took most device time;
 * ``idle_gaps`` — device idle time, summed by what the host was doing in
   each gap: the innermost host span that covers the gap's midpoint.
@@ -42,6 +45,7 @@ class TraceSummary:
     window_s: float
     busy_s: float
     gemm_s: float
+    kernel_s: dict  # kernel_name -> device seconds
     n_device_events: int
     device_ops: list
     idle_gaps: list
@@ -68,11 +72,16 @@ def newest_xplane(trace_dir: Path) -> Path:
     return Path(found[-1])
 
 
+def kernel_name(name: str) -> str | None:
+    """The instruction of an op event that is a Mosaic custom call, without
+    its numeric suffix (``%qmatmul``); None for any other op."""
+    return name.split(" = ", 1)[0].split(".", 1)[0] if CUSTOM_CALL in name else None
+
+
 def is_gemm_kernel(name: str) -> bool:
     """An op event of the scheduled GEMM kernel: a Mosaic custom call whose
     instruction is named after one of the GEMM wrappers."""
-    instr = name.split(" = ", 1)[0].split(".", 1)[0]
-    return CUSTOM_CALL in name and instr in GEMM_KERNELS
+    return kernel_name(name) in GEMM_KERNELS
 
 
 def op_key(name: str) -> str:
@@ -149,6 +158,7 @@ def reduce_trace(path: Path) -> TraceSummary:
             start_ns, stop_ns = stats["profile_start_time"], stats["profile_stop_time"]
     busy, gemm_ns, n_events = [], 0.0, 0
     per_op: dict[str, float] = {}
+    per_kernel: dict[str, float] = {}
     first_chip_busy = None
     for plane in planes:
         if not plane.name.startswith("/device:TPU:"):
@@ -161,6 +171,9 @@ def reduce_trace(path: Path) -> TraceSummary:
                 n_events += 1
                 if is_gemm_kernel(e.name):
                     gemm_ns += e.duration_ns
+                kernel = kernel_name(e.name)
+                if kernel is not None:
+                    per_kernel[kernel] = per_kernel.get(kernel, 0.0) + e.duration_ns
                 key = op_key(e.name)
                 per_op[key] = per_op.get(key, 0.0) + e.duration_ns
                 intervals.append((e.start_ns, e.start_ns + e.duration_ns))
@@ -193,6 +206,7 @@ def reduce_trace(path: Path) -> TraceSummary:
         window_s=window_ns / 1e9,
         busy_s=(sum(busy) / len(busy) / 1e9) if busy else 0.0,
         gemm_s=gemm_ns / 1e9,
+        kernel_s={k: ns / 1e9 for k, ns in sorted(per_kernel.items())},
         n_device_events=n_events,
         device_ops=[[name, ns / 1e9] for name, ns in ops],
         idle_gaps=[[name, ns / 1e9] for name, ns in gaps],

@@ -1,7 +1,8 @@
 """The benchmark's arithmetic, on the CPU: operation and byte counts from
 the configurations' shapes, the peaks table, percentiles over every
-request timed from its due time, the arrival schedule, and the readers
-that turn a run into metrics."""
+request timed from its due time, the arrival schedule, the readers
+that turn a run into metrics, and the measures of the comparison that
+decides ``correct``."""
 
 from __future__ import annotations
 
@@ -15,7 +16,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import counting, device, generator, spec  # noqa: E402
+from bench import check, counting, device, generator, spec  # noqa: E402
 from bench.record import Run, percentile  # noqa: E402
 
 
@@ -83,9 +84,9 @@ def test_latency_readers_time_every_request_from_its_due_time():
     done = due + np.r_[np.full(19, 0.002), 0.050]  # one slow request
     run = _open_run(due, done)
     assert spec.metric_reader("latency_p50_ms")(run) == pytest.approx(2.0)
-    assert spec.metric_reader("latency_p95_ms")(run) == pytest.approx(2.0)
+    assert spec.metric_reader("latency_p95_ms.stream")(run) == pytest.approx(2.0)
     done[-2] = due[-2] + 0.040  # two slow requests reach the 95th percentile
-    assert spec.metric_reader("latency_p95_ms")(_open_run(due, done)) == pytest.approx(40.0)
+    assert spec.metric_reader("latency_p95_ms.stream")(_open_run(due, done)) == pytest.approx(40.0)
 
 
 def test_serving_and_plan_readers():
@@ -137,3 +138,63 @@ def test_arrivals_are_seeded_and_at_the_rate():
     # exponential gaps: mean 1/rate, and as many above the mean as e**-1 says
     assert gaps.mean() == pytest.approx(1 / 2000)
     assert np.mean(gaps > 1 / 2000) == pytest.approx(np.exp(-1), abs=0.01)
+
+
+def test_attn_fallback_share_reads_the_window_counters():
+    read = spec.metric_reader("attn_fallback_share")
+    run = _open_run(np.zeros(2), np.ones(2))
+    assert read(run) is None  # a run that took no counters
+    run.counters = {"attn_epilogue_rows": 0, "attn_fallback_rows": 0}
+    assert read(run) is None  # a cell with no fused attention epilogue
+    run.counters = {"attn_epilogue_rows": 4864, "attn_fallback_rows": 135}
+    assert read(run) == pytest.approx(100 * 135 / 4864)
+
+
+def test_exact_measures_count_elements_and_missing_answers():
+    want = np.arange(12, dtype=np.int8).reshape(3, 4)
+    got = [want[0], want[1].copy(), None]
+    got[1][2] += 1
+    v = check.compare(got, want, {"wrong_elements": 0, "unanswered": 0})
+    assert v.numbers == {"wrong_elements": (1, 0), "unanswered": (1, 0)}
+    assert v.compared == 2 and not v.correct
+    assert v.lines()[0] == "check: wrong_elements 1 limit 0"
+    wrong_type = check.compare([want[0].astype(np.int32)], want[:1], {"wrong_elements": 0,
+                                                                     "unanswered": 0})
+    assert wrong_type.numbers["wrong_elements"] == (4, 0)
+
+
+def test_float_measures_relative_to_each_answers_reference():
+    want = np.asarray([[3.0, -4.0], [0.3, 0.4]], np.float32)  # RMS 3.5355, 0.35355
+    got = want.copy()
+    got[1, 0] += 0.01  # large beside its own answer, small beside the first
+    limits = {"max_rel_error": 0.02, "rel_l2_error": 0.01, "unanswered": 0}
+    v = check.compare(list(got), want, limits)
+    assert v.numbers["max_rel_error"][0] == pytest.approx(0.01 / np.sqrt(0.125), rel=1e-6)
+    assert v.numbers["rel_l2_error"][0] == pytest.approx(0.01 / 0.5, rel=1e-6)
+    assert not v.correct
+    assert check.compare(list(want), want, limits).correct
+
+
+@pytest.mark.parametrize("got", [
+    np.asarray([1.0, np.nan], np.float32),  # not finite
+    np.asarray([1.0, 2.0], np.float64),  # another type
+    np.asarray([1.0, 2.0, 3.0], np.float32),  # another shape
+])
+def test_float_measures_read_inf_where_no_difference_can_be_taken(got):
+    want = np.asarray([[1.0, 2.0]], np.float32)
+    v = check.compare([got], want, {"max_rel_error": 1.0, "unanswered": 0})
+    assert v.numbers["max_rel_error"][0] == np.inf and not v.correct
+    import json
+
+    assert json.loads(json.dumps(v.as_json()))["max_rel_error"]["value"] == "inf"
+
+
+@pytest.mark.parametrize("limits", [
+    {"max_abs_error": 0.1, "unanswered": 0},  # a name compare does not know
+    {"wrong_elements": 0},  # unanswered not named
+    {"unanswered": 0},  # no measure of the answers
+])
+def test_limits_compare_does_not_know_are_an_error(limits):
+    want = np.zeros((1, 2), np.float32)
+    with pytest.raises(ValueError, match="check.limits"):
+        check.compare([want[0]], want, limits)

@@ -35,14 +35,37 @@ def test_top_level_keys_and_limits():
 
 
 def test_cells_in_order_on_one_chip():
-    assert CELLS == ["toycar.stream", "musicgen.prefill512"]
-    assert {c["name"] for c in BENCH["configs"]} == {"toycar", "musicgen_medium_int8"}
-    assert all(w["chips"] == 1 for w in BENCH["workloads"])
+    # the first two cells stay first, so that neither is dropped quietly;
+    # a configuration and its cells join after them
+    assert CELLS[:2] == ["toycar.stream", "musicgen.prefill512"]
+    assert spec.layout_errors(BENCH) == []
+
+
+LAYOUT_FAULTS = {
+    "cell_twice": (lambda b: b["workloads"].append(dict(b["workloads"][0])),
+                   "cell 'toycar.stream' appears 2"),
+    "config_unused": (lambda b: b["workloads"][0].update(config="nope"),
+                      "configuration 'toycar' has no cell"),
+    "two_chips": (lambda b: b["workloads"][1].update(chips=2), "asks for 2 chips"),
+    "all_on_four": (lambda b: [w.update(chips=4) for w in b["workloads"]],
+                    "2 of 2 cells on 4 chips"),
+    "unknown_cell": (lambda b: b["per_layer"][-1]["workloads"].append("no.cell"),
+                     "lists unknown cell"),
+    "too_many_cells": (lambda b: b["workloads"].extend(
+        dict(b["workloads"][0], name=f"c{i}", traffic=f"t{i}") for i in range(23)), "25 cells"),
+}
+
+
+@pytest.mark.parametrize("change, error", LAYOUT_FAULTS.values(), ids=list(LAYOUT_FAULTS))
+def test_layout_errors_name_what_is_wrong(change, error):
+    bench = json.loads(json.dumps(BENCH))
+    change(bench)
+    assert any(error in e for e in spec.layout_errors(bench)), spec.layout_errors(bench)
 
 
 def test_end_to_end_metrics_are_the_four():
     assert [m["name"] for m in BENCH["end_to_end"]] == [
-        "setup_s", "latency_p50_ms", "latency_p95_ms", "throughput"
+        "setup_s", "latency_p50_ms", "throughput"
     ]
     for m in BENCH["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.25

@@ -1,5 +1,6 @@
 """The reduction from a profiler trace to device numbers: the union of
-device op intervals, the GEMM kernels' device time, and the host activity
+device op intervals, the GEMM kernels' and every Mosaic kernel's device
+time, and the host activity
 each idle gap falls in — on synthetic intervals and on a small trace
 recorded on a TPU v5e (``bench/testdata/``)."""
 
@@ -49,6 +50,9 @@ def test_gemm_kernel_class_and_op_key():
     assert not tracing.is_gemm_kernel(pad)
     assert not tracing.is_gemm_kernel(QGEMM.replace("%qmatmul.1", "%softmax.1"))
     assert tracing.op_key(QGEMM) == "%qmatmul.1 = s8[16,128]"
+    assert tracing.kernel_name(QGEMM) == "%qmatmul"
+    assert tracing.kernel_name(QGEMM.replace("%qmatmul.1", "%attn_scores.12")) == "%attn_scores"
+    assert tracing.kernel_name(pad) is None
 
 
 def test_recorded_trace_reduces_to_pinned_numbers():
@@ -64,6 +68,9 @@ def test_recorded_trace_reduces_to_pinned_numbers():
         "%qmatmul.1 = s8[320,128]", "%qmatmul.1 = s8[320,640]"
     ]
     assert s.device_ops[0][1] + s.device_ops[2][1] == pytest.approx(s.gemm_s)
+    # the one Mosaic kernel of the trace is the GEMM; gemm_s is its sum
+    assert s.kernel_s == {"%qmatmul": pytest.approx(8.0667e-05)}
+    assert sum(s.kernel_s.get(k, 0.0) for k in tracing.GEMM_KERNELS) == s.gemm_s
     assert len(s.device_ops) == len(s.idle_gaps) == tracing.TOP
     assert s.idle_gaps[0][0] == "no host span"
     # device time is a sum over events, so the top ops cannot exceed busy time

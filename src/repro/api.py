@@ -461,7 +461,8 @@ def _check_offload(module) -> None:
         f"{n.name}: {n.op} {list(n.shape)} ({n.dtype})"
         for n in module.graph.toposort()
         if n.target != "accel"
-        and n.op.replace("generalized_", "") in ("dense", "conv2d", "matmul")
+        and n.op.replace("generalized_", "")
+        in ("dense", "conv2d", "matmul", "grouped_dense")
     ]
     if left_on_host:
         left_on_host.append(

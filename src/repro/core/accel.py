@@ -33,7 +33,7 @@ from typing import Any, Callable
 
 from repro.core.arch_spec import ArchSpec
 
-SUPPORTED_OPS = ("dense", "conv2d", "matmul")
+SUPPORTED_OPS = ("dense", "conv2d", "matmul", "grouped_dense")
 INTRINSIC_KINDS = ("compute", "memory", "config")
 
 

@@ -152,6 +152,15 @@ def make_tpu_v5e_description() -> AcceleratorDescription:
         requant = acc.astype(jnp.float32) * (scale_in * scale_w / scale_out)
         return jnp.clip(jnp.round(requant), -128, 127).astype(jnp.int8)
 
+    @desc.register_core_compute(
+        "tpu_grouped_qgemm_int8", op="grouped_dense", quantized=True
+    )
+    def grouped_qdense_int8(x_q, w_q, group_sizes):
+        # rows sorted by expert, one int8 weight panel per expert
+        import jax.lax as lax
+
+        return lax.ragged_dot(x_q, w_q, group_sizes, preferred_element_type=jnp.int32)
+
     @desc.register_core_compute("tpu_gemm_conv", op="conv2d")
     def conv_as_gemm(cols, w, bias=None):
         return dense_bf16(cols, w, bias)
@@ -188,6 +197,17 @@ def make_tpu_v5e_description() -> AcceleratorDescription:
             a_tile, b_tile, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
         )
+
+    # the grouped GEMM runs each expert's row tiles on the int8 MXU path
+    @desc.register_hw_intrinsic(
+        "tpu.mxu_matmul_grouped",
+        kind="compute",
+        tag="tpu_grouped_qgemm_int8",
+        tile_limits={"N": MXU_DIM, "C": MXU_DIM, "K": MXU_DIM},
+        dataflow="OS",
+    )
+    def mxu_matmul_grouped(a_tile, b_tile, acc_tile):
+        return mxu_matmul_int8(a_tile, b_tile, acc_tile)
 
     # conv reuses the bf16 MXU intrinsic after im2col.
     @desc.register_hw_intrinsic(

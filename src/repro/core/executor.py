@@ -21,12 +21,20 @@ from repro.core.accel import AcceleratorDescription
 from repro.core.collective import collective_cycles, collective_fn
 from repro.core.ir import (
     COLLECTIVE_OPS,
+    MOE_HOST_OPS,
     Graph,
     Node,
     execute_node,
     gelu_ref,
     kv_append_ref,
     max_pool2d_ref,
+    moe_combine_ref,
+    moe_dispatch_ref,
+    moe_group_sizes_ref,
+    moe_route_ref,
+    rms_norm_ref,
+    rope_ref,
+    silu_mul_ref,
 )
 from repro.core.simulator import simulate
 from repro.core.strategy import Strategy, dtype_bytes, gemm_instances
@@ -50,7 +58,7 @@ _EPILOGUE_OPS = {
     "mul",
     "softmax",
     "max_pool2d",
-}
+} | MOE_HOST_OPS
 
 
 @dataclass
@@ -144,6 +152,26 @@ def compile_host_op(n: Node) -> Callable[..., np.ndarray]:
         return lambda cache: np.asarray(cache)
     if op == "kv_cache_append":
         return kv_append_ref
+    if op == "rms_norm":
+        s_in, s_out, eps = attrs["in_scale"], attrs["out_scale"], attrs["eps"]
+        return lambda x: rms_norm_ref(x, s_in, s_out, eps)
+    if op == "rope":
+        s_in, s_out = attrs["in_scale"], attrs["out_scale"]
+        return lambda x, cos, sin: rope_ref(x, cos, sin, s_in, s_out)
+    if op == "silu_mul":
+        s_in, s_out = attrs["in_scale"], attrs["out_scale"]
+        return lambda x: silu_mul_ref(x, s_in, s_out)
+    if op == "moe_route":
+        top_k = attrs["top_k"]
+        return lambda logits: moe_route_ref(logits, top_k)
+    if op == "moe_group_sizes":
+        n_experts = attrs["n_experts"]
+        return lambda idx: moe_group_sizes_ref(idx, n_experts)
+    if op == "moe_dispatch":
+        return moe_dispatch_ref
+    if op == "moe_combine":
+        l_scale, scale = attrs["logit_scale"], attrs["scale"]
+        return lambda y, idx, logits: moe_combine_ref(y, idx, logits, l_scale, scale)
     # anything else (dense/conv left on the host, exotic ops): fall back to
     # the reference interpreter for this node only.
     return lambda *ins, _n=n: execute_node(_n, list(ins))

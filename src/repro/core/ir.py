@@ -64,6 +64,29 @@ COLLECTIVE_OPS = {"all_gather", "all_reduce", "reduce_scatter"}
 CACHE_OPS = {"kv_cache_read", "kv_cache_append"}
 HOST_OPS |= CACHE_OPS
 
+# Host ops of a decoder block with routed experts (Mixtral-style): the int8
+# RMSNorm, rotary positions, the SwiGLU gate, and the router's top-k with
+# the permutation that sorts token-expert rows by expert and the weighted
+# combine that undoes it.  Float math runs in float64 on the host and every
+# result is int8 (or an int32 index), so each has one bit-exact definition
+# (the ``*_ref`` functions below) that the interpreter and the planned host
+# closures share.
+MOE_HOST_OPS = {
+    "rms_norm",
+    "rope",
+    "silu_mul",
+    "moe_route",
+    "moe_group_sizes",
+    "moe_dispatch",
+    "moe_combine",
+}
+HOST_OPS |= MOE_HOST_OPS
+
+# The grouped dense: rows sorted by expert, one weight panel per expert,
+# group sizes known only at run time (``jax.lax.ragged_dot``), and its
+# legalized form with the fused requantize epilogue.
+GROUPED_OPS = {"grouped_dense", "generalized_grouped_dense"}
+
 
 @dataclass(frozen=True)
 class CacheSpec:
@@ -454,6 +477,81 @@ def kv_append_ref(cache: np.ndarray, update: np.ndarray, pos: np.ndarray) -> np.
     return out
 
 
+def rms_norm(x: Node, *, in_scale: float, out_scale: float, eps: float) -> Node:
+    """int8 RMSNorm over the last axis (``rms_norm_ref``); the norm weight is
+    folded into the next projection's weights."""
+    attrs = {"in_scale": in_scale, "out_scale": out_scale, "eps": eps}
+    return Node("rms_norm", [x], attrs, shape=x.shape, dtype="int8")
+
+
+def rope(x: Node, cos: Node, sin: Node, *, in_scale: float, out_scale: float) -> Node:
+    """Rotary positions on ``x[..., S, head_dim]`` (``rope_ref``); ``cos`` and
+    ``sin`` are ``[S, head_dim]`` tables."""
+    if cos.shape != sin.shape or tuple(x.shape[-2:]) != tuple(cos.shape):
+        raise ValueError(f"rope tables {cos.shape}/{sin.shape} for input {x.shape}")
+    attrs = {"in_scale": in_scale, "out_scale": out_scale}
+    return Node("rope", [x, cos, sin], attrs, shape=x.shape, dtype="int8")
+
+
+def silu_mul(x: Node, *, in_scale: float, out_scale: float) -> Node:
+    """The SwiGLU gate over ``x[..., 2F]`` = [gate | up] -> int8 ``[..., F]``
+    (``silu_mul_ref``)."""
+    if x.shape[-1] % 2:
+        raise ValueError(f"silu_mul needs an even last dim, got {x.shape}")
+    shape = (*x.shape[:-1], x.shape[-1] // 2)
+    attrs = {"in_scale": in_scale, "out_scale": out_scale}
+    return Node("silu_mul", [x], attrs, shape=shape, dtype="int8")
+
+
+def moe_route(logits: Node, top_k: int) -> Node:
+    """The ``top_k`` experts of each token, best first, ties to the lower
+    expert index: int32 ``[T, top_k]`` from router logits ``[T, E]``."""
+    if len(logits.shape) != 2 or not 0 < top_k <= logits.shape[1]:
+        raise ValueError(f"moe_route top_k {top_k} over logits {logits.shape}")
+    return Node("moe_route", [logits], {"top_k": top_k},
+                shape=(logits.shape[0], top_k), dtype="int32")
+
+
+def moe_group_sizes(idx: Node, n_experts: int) -> Node:
+    """Routed rows per expert: int32 ``[n_experts]``."""
+    return Node("moe_group_sizes", [idx], {"n_experts": n_experts},
+                shape=(n_experts,), dtype="int32")
+
+
+def moe_dispatch(x: Node, idx: Node) -> Node:
+    """Each token's row once per routed expert, sorted by expert (stably:
+    token, then slot, within an expert): ``[T * top_k, D]``."""
+    if len(x.shape) != 2 or len(idx.shape) != 2 or x.shape[0] != idx.shape[0]:
+        raise ValueError(f"moe_dispatch of {x.shape} by routes {idx.shape}")
+    return Node("moe_dispatch", [x, idx],
+                shape=(idx.shape[0] * idx.shape[1], x.shape[1]), dtype=x.dtype)
+
+
+def grouped_dense(x: Node, w: Node, group_sizes: Node, **attrs) -> Node:
+    """``x[R, C]`` with rows sorted by group, ``w[G, C, K]``: the rows of
+    group g times ``w[g]`` (``group_sizes[g]`` rows each) -> ``[R, K]``."""
+    out_dtype = attrs.pop("out_dtype", "int32" if x.dtype.startswith("int") else x.dtype)
+    if len(x.shape) != 2 or len(w.shape) != 3 or x.shape[1] != w.shape[1]:
+        raise ValueError(f"grouped dense shape mismatch {x.shape} @ {w.shape}")
+    if tuple(group_sizes.shape) != (w.shape[0],):
+        raise ValueError(f"group sizes {group_sizes.shape} for {w.shape[0]} groups")
+    return Node("grouped_dense", [x, w, group_sizes], attrs,
+                shape=(x.shape[0], w.shape[2]), dtype=out_dtype)
+
+
+def moe_combine(y: Node, idx: Node, logits: Node, *, logit_scale: float,
+                scale: float) -> Node:
+    """Each token's expert rows (``y`` in dispatch order) weighted by the
+    softmax of its top-k router logits and summed -> int8 ``[T, D]``
+    (``moe_combine_ref``)."""
+    t, k = idx.shape
+    if y.shape[0] != t * k or logits.shape[0] != t:
+        raise ValueError(f"moe_combine of {y.shape} by routes {idx.shape}")
+    attrs = {"logit_scale": logit_scale, "scale": scale}
+    return Node("moe_combine", [y, idx, logits], attrs, shape=(t, y.shape[1]),
+                dtype="int8")
+
+
 def add(a: Node, b: Node) -> Node:
     return Node("add", [a, b], shape=_binary_shape(a, b), dtype=a.dtype)
 
@@ -496,10 +594,153 @@ def max_pool2d_ref(x: np.ndarray, size: int, stride: int) -> np.ndarray:
     return out
 
 
+def _to_int8(y: np.ndarray) -> np.ndarray:
+    """Round half to even and saturate to int8."""
+    return np.clip(np.rint(y), -128, 127).astype(np.int8)
+
+
+#: elements of one block of rows of a routed-expert host op: its float64
+#: temporaries then stay in cache, where whole-array temporaries of a large
+#: layer are fresh allocations the host page-faults through.  Each op
+#: computes every row alone, so the bits do not depend on the blocks.
+HOST_BLOCK_ELEMS = 1 << 16
+
+
+def _row_blocks(n_rows: int, row_elems: int):
+    step = max(1, HOST_BLOCK_ELEMS // max(row_elems, 1))
+    return (slice(i, i + step) for i in range(0, n_rows, step))
+
+
+def rms_norm_ref(x: np.ndarray, in_scale: float, out_scale: float, eps: float) -> np.ndarray:
+    """``y = x·s / sqrt(mean((x·s)²) + eps)`` over the last axis, to int8 at
+    ``out_scale``.  The sum of squares is exact in int64; then, in float64:
+    ``ms = ss · s² / D``, ``r = 1 / sqrt(ms + eps)``, ``y = (x · s) · r``,
+    ``out = rint(y / out_scale)``."""
+    x2 = x.reshape(-1, x.shape[-1])
+    out = np.empty(x2.shape, np.int8)
+    for rows in _row_blocks(*x2.shape):
+        xi = x2[rows].astype(np.int64)
+        ss = np.sum(xi * xi, axis=-1, keepdims=True).astype(np.float64)
+        ms = ss * (in_scale * in_scale) / x.shape[-1]
+        r = 1.0 / np.sqrt(ms + eps)
+        out[rows] = _to_int8((xi.astype(np.float64) * in_scale) * r / out_scale)
+    return out.reshape(x.shape)
+
+
+def rope_ref(x: np.ndarray, cos: np.ndarray, sin: np.ndarray, in_scale: float,
+             out_scale: float) -> np.ndarray:
+    """Rotate-half rotary positions in float64 on ``x[..., S, head_dim]``:
+    ``xf = x · s``, ``y = xf · cos + [-xf₂, xf₁] · sin`` (halves of the last
+    axis; the float32 tables widen exactly), ``out = rint(y / out_scale)``."""
+    x3 = x.reshape(-1, *x.shape[-2:])
+    out = np.empty(x3.shape, np.int8)
+    c, sn = cos.astype(np.float64), sin.astype(np.float64)
+    h = x.shape[-1] // 2
+    for rows in _row_blocks(x3.shape[0], x3.shape[1] * x3.shape[2]):
+        xf = x3[rows].astype(np.float64) * in_scale
+        rot = np.concatenate([-xf[..., h:], xf[..., :h]], axis=-1)
+        out[rows] = _to_int8((xf * c + rot * sn) / out_scale)
+    return out.reshape(x.shape)
+
+
+def silu_mul_ref(x: np.ndarray, in_scale: float, out_scale: float) -> np.ndarray:
+    """SwiGLU on ``[gate | up]`` in float64: ``g = gate · s``, ``u = up · s``,
+    ``a = g / (1 + exp(-g)) · u``, ``out = rint(a / out_scale)``."""
+    f = x.shape[-1] // 2
+    x2 = x.reshape(-1, x.shape[-1])
+    out = np.empty((x2.shape[0], f), np.int8)
+    for rows in _row_blocks(x2.shape[0], f):
+        g = x2[rows, :f].astype(np.float64) * in_scale
+        u = x2[rows, f:].astype(np.float64) * in_scale
+        out[rows] = _to_int8(g / (1.0 + np.exp(-g)) * u / out_scale)
+    return out.reshape(*x.shape[:-1], f)
+
+
+def moe_route_ref(logits: np.ndarray, top_k: int) -> np.ndarray:
+    """The top-k experts of each row, best first; equal logits go to the
+    lower expert index (a stable sort of the negated logits)."""
+    order = np.argsort(-logits.astype(np.int64), axis=-1, kind="stable")
+    return order[..., :top_k].astype(np.int32)
+
+
+def moe_group_sizes_ref(idx: np.ndarray, n_experts: int) -> np.ndarray:
+    return np.bincount(idx.reshape(-1), minlength=n_experts).astype(np.int32)
+
+
+def moe_order(idx: np.ndarray) -> np.ndarray:
+    """The dispatch order: flat (token, slot) pair numbers sorted by expert,
+    stably (token, then slot, within an expert)."""
+    return np.argsort(idx.reshape(-1), kind="stable")
+
+
+def moe_dispatch_ref(x: np.ndarray, idx: np.ndarray) -> np.ndarray:
+    return x[moe_order(idx) // idx.shape[-1]]
+
+
+def grouped_dense_ref(x: np.ndarray, w: np.ndarray, group_sizes: np.ndarray) -> np.ndarray:
+    """Rows of group g times ``w[g]``; integer operands accumulate in int64."""
+    ends = np.cumsum(np.asarray(group_sizes, np.int64))
+    if ends[-1] != x.shape[0]:
+        raise ValueError(f"group sizes sum to {ends[-1]}, not the {x.shape[0]} rows")
+    wide = np.int64 if x.dtype.kind in "iu" else None
+    out = np.zeros((x.shape[0], w.shape[2]), wide or np.result_type(x, w))
+    start = 0
+    for g, end in enumerate(ends):
+        if end > start:
+            xs, wg = x[start:end], w[g]
+            out[start:end] = xs.astype(wide) @ wg.astype(wide) if wide else xs @ wg
+        start = end
+    return out
+
+
+def moe_combine_ref(y: np.ndarray, idx: np.ndarray, logits: np.ndarray,
+                    logit_scale: float, scale: float) -> np.ndarray:
+    """In float64: ``l = logits[t, idx[t]] · logit_scale``, the gates
+    ``g = exp(l - max l) / Σ exp(l - max l)`` over the token's k routes,
+    ``acc = g₀·y₀ + g₁·y₁ + …`` in slot order (``y_j`` the row its j-th
+    expert returned), ``out = rint(acc · scale)``."""
+    t, k = idx.shape
+    pos = np.empty(t * k, np.int64)
+    pos[moe_order(idx)] = np.arange(t * k)  # the dispatched row of each pair
+    pos = pos.reshape(t, k)
+    lg = np.take_along_axis(logits.astype(np.int64), idx.astype(np.int64), axis=-1)
+    lg = lg.astype(np.float64) * logit_scale
+    e = np.exp(lg - np.max(lg, axis=-1, keepdims=True))
+    g = e / np.sum(e, axis=-1, keepdims=True)
+    out = np.empty((t, y.shape[-1]), np.int8)
+    for rows in _row_blocks(t, y.shape[-1]):
+        acc = g[rows, 0:1] * y[pos[rows, 0]].astype(np.float64)
+        for j in range(1, k):
+            acc = acc + g[rows, j : j + 1] * y[pos[rows, j]].astype(np.float64)
+        out[rows] = _to_int8(acc * scale)
+    return out
+
+
 def execute_node(n: Node, inputs: list[np.ndarray]) -> np.ndarray:
     op = n.op
     if op == "const":
         return n.value
+    if op == "rms_norm":
+        a = n.attrs
+        return rms_norm_ref(inputs[0], a["in_scale"], a["out_scale"], a["eps"])
+    if op == "rope":
+        return rope_ref(*inputs, n.attrs["in_scale"], n.attrs["out_scale"])
+    if op == "silu_mul":
+        return silu_mul_ref(inputs[0], n.attrs["in_scale"], n.attrs["out_scale"])
+    if op == "moe_route":
+        return moe_route_ref(inputs[0], n.attrs["top_k"])
+    if op == "moe_group_sizes":
+        return moe_group_sizes_ref(inputs[0], n.attrs["n_experts"])
+    if op == "moe_dispatch":
+        return moe_dispatch_ref(*inputs)
+    if op == "moe_combine":
+        return moe_combine_ref(*inputs, n.attrs["logit_scale"], n.attrs["scale"])
+    if op in GROUPED_OPS:
+        acc = grouped_dense_ref(*inputs[:3])
+        if n.attrs.get("quantized"):
+            acc = np.round(acc.astype(np.float64) * n.attrs["requant_scale"])
+            acc = np.clip(acc, n.attrs["clip_lo"], n.attrs["clip_hi"])
+        return acc.astype(n.dtype)
     if op == "dense":
         x, w = inputs
         if n.attrs.get("transpose_b"):

@@ -32,6 +32,7 @@ Epilogue attribute contract on generalized ops (set by the passes):
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import threading
@@ -44,8 +45,9 @@ import numpy as np
 from repro.core import trace
 from repro.core.accel import AcceleratorDescription
 from repro.core.intrinsics import HardwareIntrinsicGenerator
-from repro.core.ir import Node, gelu_ref, max_pool2d_ref
+from repro.core.ir import Node, execute_node, gelu_ref, max_pool2d_ref
 from repro.core.mapping import MappingGenerator
+from repro.core.scheduler import grouped_block_m
 from repro.core.strategy import Strategy
 
 
@@ -79,6 +81,10 @@ def make_accel_executor(
                 f"generalized ops must provide them"
             )
 
+    if node.op.replace("generalized_", "") == "grouped_dense":
+        return _make_grouped_executor(
+            desc, mapping_gen, node, strategy, runs_pallas(desc, use_pallas)
+        )
     if runs_pallas(desc, use_pallas):
         return _make_pallas_executor(
             desc, mapping_gen, node, strategy, fused_epilogue
@@ -542,6 +548,100 @@ def _make_pallas_executor(
             _attn_scores_step, node, lambda on, x, w: _device_out(on, x, w, None)
         )
     return pallas_exec
+
+
+# ---------------------------------------------------------------------------
+# the grouped (routed-expert) dense
+# ---------------------------------------------------------------------------
+
+#: rows of an int8 tile the chip packs into one vreg sublane group: the row
+#: tile of an int8 grouped GEMM is a multiple of it on Mosaic
+INT8_SUBLANE = 32
+
+
+def grouped_kernel_config(desc, mapping_gen, node: Node, strategy: Strategy):
+    """The grouped kernel's config: the schedule's epilogue and column tile,
+    with three grouped choices (``kernels/grouped.py``).  ``block_k`` is the
+    whole K, so an expert's weight panel stays put across its row tiles.
+    ``block_m`` is ``scheduler.grouped_block_m`` for the rows routed over the
+    experts, capped by the schedule's row tile.  ``block_n`` is the widest
+    column tile that divides N (lane-aligned on the chip), at most the
+    schedule's, whose double-buffered whole-K blocks fit the VMEM budget."""
+    cfg = kernel_config_for(desc, mapping_gen, node, strategy)
+    rows = node.inputs[0].shape[0]
+    n_groups, k, n = node.inputs[1].shape
+    align = 8 if cfg.interpret else INT8_SUBLANE
+    bm = grouped_block_m(rows, n_groups, align, max(cfg.block_m, align))
+    levels = desc.arch.buffered_levels()
+    budget = desc.arch.levels[levels[0]].size_bytes if levels else None
+    lane = 1 if cfg.interpret else 128
+
+    def fits(bn):
+        # x and w blocks and the int8 out block double-buffered, plus the
+        # int32 product
+        return budget is None or 2 * bm * k + 2 * k * bn + 6 * bm * bn <= budget
+
+    widths = [d for d in range(1, n + 1) if n % d == 0 and (d % lane == 0 or d == n)]
+    capped = [d for d in widths if d <= cfg.block_n] or widths[:1]
+    bn = max((d for d in capped if fits(d)), default=capped[0])
+    return dataclasses.replace(
+        cfg, block_m=bm, block_k=k, block_n=bn, has_bias=False, out_dtype=node.dtype
+    )
+
+
+def _make_grouped_executor(
+    desc, mapping_gen, node: Node, strategy: Strategy, pallas: bool
+) -> Callable:
+    """One accelerator step for a (generalized) grouped dense: every expert's
+    rows in one launch of ``kernels.grouped.grouped_qmatmul``.  The plan
+    specializes the step over its constant expert weights
+    (``specialize_consts``): they are uploaded by the first call and stay
+    on the device (``_Resident``), so a call moves only the routed rows, the
+    group sizes and the result.  Counts ``expert_rows``, ``expert_pad_rows``
+    and ``expert_rows_peak`` (``repro.core.trace``).  Off the Pallas path,
+    or unquantized, the step is the IR's reference."""
+    from repro.kernels import grouped as kgrouped
+
+    if not (pallas and resolved_fused_epilogue(node, strategy)):
+        return lambda *ins: execute_node(node, list(ins))
+    cfg = grouped_kernel_config(desc, mapping_gen, node, strategy)
+    n_groups = node.inputs[1].shape[0]
+
+    def run_kernel(x_j, w_j, gs_j):
+        return kgrouped.grouped_qmatmul(x_j, w_j, gs_j, cfg=cfg)
+
+    def run(on, x, w_j, group_sizes):
+        gs = np.asarray(group_sizes)
+        _, pad, peak = kgrouped.tile_layout(gs, cfg.block_m)
+        trace.count_expert_rows(int(gs.sum()), pad, n_groups * peak)
+        x_j, gs_j = _to_device(x, on), _to_device(gs, on)
+        trace.count_launch()
+        if on:
+            with trace.TraceAnnotation("repro.launch"):
+                out = run_kernel(x_j, w_j, gs_j)
+        else:
+            out = run_kernel(x_j, w_j, gs_j)
+        return _to_host(out, on)
+
+    def grouped_exec(x, w, group_sizes):
+        on = trace.enabled()
+        return run(on, x, _to_device(w, on), group_sizes)
+
+    def specialize_consts(consts: dict[int, np.ndarray]):
+        if 1 not in consts:
+            return None
+        resident = _Resident(np.asarray(consts[1]))
+
+        def grouped_resident(x, w, group_sizes):
+            on = trace.enabled()
+            return run(on, x, resident.get(on), group_sizes)
+
+        return grouped_resident
+
+    grouped_exec.kernel_config = cfg
+    grouped_exec.run_kernel = run_kernel
+    grouped_exec.specialize_consts = specialize_consts
+    return grouped_exec
 
 
 # ---------------------------------------------------------------------------

@@ -63,6 +63,23 @@ def _gen_op_for(core: Node) -> str:
 # ---------------------------------------------------------------------------
 
 
+def _quantized_attrs(core: Node, rq: Node, clip: Node | None) -> dict:
+    """The fused requantize epilogue of ``clip(requantize(core))``; without
+    a clip, the requantize's own saturation to its int dtype."""
+    lo, hi = (
+        (clip.attrs["lo"], clip.attrs["hi"])
+        if clip is not None
+        else (int(np.iinfo(rq.dtype).min), int(np.iinfo(rq.dtype).max))
+    )
+    return {
+        **core.attrs,
+        "quantized": True,
+        "requant_scale": rq.attrs["scale"],
+        "clip_lo": lo,
+        "clip_hi": hi,
+    }
+
+
 @rule(
     "fuse-quantized-epilogue",
     P(
@@ -76,17 +93,11 @@ def _gen_op_for(core: Node) -> str:
 )
 def _fuse_quantized(m: Match, graph: Graph) -> Node | None:
     """clip(requantize(bias_add(dense|conv2d))) -> one generalized op."""
-    core, rq, root = m["core"], m["rq"], m.root
+    core, root = m["core"], m.root
     return Node(
         _gen_op_for(core),
         [core.inputs[0], core.inputs[1], m["bias"]],
-        {
-            **core.attrs,
-            "quantized": True,
-            "requant_scale": rq.attrs["scale"],
-            "clip_lo": root.attrs["lo"],
-            "clip_hi": root.attrs["hi"],
-        },
+        _quantized_attrs(core, m["rq"], root),
         shape=root.shape,
         dtype=root.dtype,
     )
@@ -127,7 +138,55 @@ def _fuse_bias(m: Match, graph: Graph) -> Node | None:
     )
 
 
-LEGALIZE_RULES = (_fuse_quantized, _fuse_activation, _fuse_bias)
+@rule(
+    "fuse-quantized-epilogue-nobias",
+    P(
+        "clip",
+        P(
+            "requantize",
+            P("dense", capture="core", where=lambda n: len(n.inputs[1].shape) == 2),
+            capture="rq",
+        ),
+    ),
+)
+def _fuse_quantized_nobias(m: Match, graph: Graph) -> Node | None:
+    """clip(requantize(dense)) with a 2-D weight and no bias (as in models
+    without projection biases) -> one generalized op with an absent bias."""
+    core, root = m["core"], m.root
+    return Node(
+        "generalized_dense",
+        [core.inputs[0], core.inputs[1], None],
+        _quantized_attrs(core, m["rq"], root),
+        shape=root.shape,
+        dtype=root.dtype,
+    )
+
+
+@rule(
+    "fuse-grouped-requantize",
+    P("requantize", P("grouped_dense", capture="core"), capture="rq",
+      where=lambda n: n.dtype.startswith("int")),
+)
+def _fuse_grouped(m: Match, graph: Graph) -> Node | None:
+    """requantize(grouped_dense) to an int dtype -> the quantized grouped op
+    (the requantize saturates to its dtype)."""
+    core, root = m["core"], m.root
+    return Node(
+        "generalized_grouped_dense",
+        list(core.inputs),
+        _quantized_attrs(core, m["rq"], None),
+        shape=root.shape,
+        dtype=root.dtype,
+    )
+
+
+LEGALIZE_RULES = (
+    _fuse_quantized,
+    _fuse_activation,
+    _fuse_bias,
+    _fuse_quantized_nobias,
+    _fuse_grouped,
+)
 
 
 # ---------------------------------------------------------------------------

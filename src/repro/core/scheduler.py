@@ -176,3 +176,22 @@ class ExtendedCosaScheduler:
             n_infeasible=n_infeasible,
             top=tuple(ranked[:MAX_TOP_CANDIDATES]),
         )
+
+
+#: the expected padding a grouped GEMM's row tile may add, as a share of
+#: the routed rows (``grouped_block_m``)
+GROUPED_PAD_SHARE = 1 / 16
+
+
+def grouped_block_m(rows: int, n_groups: int, align: int, cap: int) -> int:
+    """The row tile of a grouped GEMM whose group sizes are known only at
+    run time (routed experts): each group starts on a tile boundary, so its
+    last tile is on average half padding, ``n_groups · (block_m - 1) / 2``
+    rows in all.  The largest ``align · 2^i`` (at most ``cap``, at least
+    ``align``) whose expected padding stays within ``GROUPED_PAD_SHARE`` of
+    the ``rows`` routed: the uneven split costs a few percent of rows, and
+    the tile stays as tall as that allows."""
+    bm = align
+    while bm * 2 <= cap and n_groups * (bm * 2 - 1) / 2 <= rows * GROUPED_PAD_SHARE:
+        bm *= 2
+    return bm

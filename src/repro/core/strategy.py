@@ -47,6 +47,8 @@ def gemm_instances(node: Node) -> int:
     base = node.op.replace("generalized_", "")
     if base == "dense" and len(node.inputs[1].shape) == 3:
         return node.inputs[0].shape[0]
+    if base == "grouped_dense":
+        return node.inputs[1].shape[0]  # one per expert
     return 1
 
 
@@ -56,10 +58,15 @@ def workload_from_node(node: Node) -> GemmWorkload:
     Weight-operand denses fold every leading input dim (the serving batch
     included) into the GEMM M dimension, so the scheduler sees the batched
     shape as ONE workload.  Batched matmuls (3-D weight) schedule the
-    per-sample GEMM; see ``gemm_instances``."""
+    per-sample GEMM; see ``gemm_instances``.  A grouped dense schedules one
+    expert's GEMM at the rows an expert gets on average (all rows over the
+    experts): its M is ragged, known only at run time."""
     x, w = node.inputs[0], node.inputs[1]
     base = node.op.replace("generalized_", "")
-    if base == "dense" and len(w.shape) == 3:
+    if base == "grouped_dense":
+        n_dim = -(-x.shape[0] // w.shape[0])
+        c_dim, k_dim = w.shape[1], w.shape[2]
+    elif base == "dense" and len(w.shape) == 3:
         # batched matmul: x[B, M, C] @ w[B, C, K]
         n_dim = x.shape[-2]
         c_dim = x.shape[-1]

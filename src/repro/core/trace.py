@@ -25,12 +25,21 @@ Span names (docs/architecture.md, "Observability"):
 * ``repro.accel.attn_scores`` — the step that runs an attention-score
   epilogue (dequantize, mask, softmax, quantize) on the device after its
   scores GEMM; ``repro.host.softmax_fallback`` inside it — the host chain
-  recomputing the rows its rounding guard flagged.
+  recomputing the rows its rounding guard flagged;
+* ``repro.accel.generalized_grouped_dense`` — one grouped expert GEMM (all
+  experts, one launch); ``repro.host.rms_norm``, ``.rope``, ``.silu_mul``,
+  ``.moe_route``, ``.moe_group_sizes``, ``.moe_dispatch``,
+  ``.moe_combine`` — the host steps of a routed-expert block.
 
 Counters: ``h2d_transfers`` / ``h2d_bytes``, ``d2h_syncs`` / ``d2h_bytes``,
 ``kernel_launches``; ``attn_epilogue_rows`` — rows of scores the device
 epilogue ran over; ``attn_fallback_rows`` — those of them the host chain
-recomputed.
+recomputed; ``expert_rows`` — token-expert rows routed into grouped
+expert GEMMs; ``expert_pad_rows`` — rows those GEMMs computed beyond the
+routed ones, from row-tile padding at expert boundaries;
+``expert_rows_peak`` — per grouped GEMM, the number of experts times the
+busiest expert's rows (``expert_rows_peak / expert_rows`` is the routing's
+imbalance: 1 when every expert gets as many rows).
 """
 
 from __future__ import annotations
@@ -49,6 +58,9 @@ COUNTERS = (
     "kernel_launches",
     "attn_epilogue_rows",
     "attn_fallback_rows",
+    "expert_rows",
+    "expert_pad_rows",
+    "expert_rows_peak",
 )
 
 _NULL = contextlib.nullcontext()
@@ -101,6 +113,13 @@ def count_attn_rows(rows: int, fallback: int) -> None:
     with _lock:
         _counts["attn_epilogue_rows"] += rows
         _counts["attn_fallback_rows"] += fallback
+
+
+def count_expert_rows(rows: int, pad: int, peak: int) -> None:
+    with _lock:
+        _counts["expert_rows"] += rows
+        _counts["expert_pad_rows"] += pad
+        _counts["expert_rows_peak"] += peak
 
 
 def snapshot() -> dict[str, int]:

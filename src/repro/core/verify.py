@@ -78,6 +78,7 @@ KNOWN_OPS = (
     ir.HOST_OPS
     | ir.GENERALIZED_OPS
     | ir.COLLECTIVE_OPS
+    | ir.GROUPED_OPS
     | {"dense", "conv2d", "input", "const"}
 )
 
@@ -144,6 +145,7 @@ _DTYPE_PRESERVING = {
     "sub",
     "mul",
     "bias_add",
+    "moe_dispatch",
 }
 
 #: ops whose output shape must equal their first operand's shape.
@@ -158,6 +160,16 @@ _SHAPE_PRESERVING = {
     "bias_add",
     "all_reduce",
     "kv_cache_read",
+}
+
+#: ops whose result has one fixed dtype, whatever their operands'.
+_FIXED_DTYPE = {
+    "rms_norm": "int8",
+    "rope": "int8",
+    "silu_mul": "int8",
+    "moe_combine": "int8",
+    "moe_route": "int32",
+    "moe_group_sizes": "int32",
 }
 
 #: fixed operand arity per op (generalized ops are special-cased: 3 inputs,
@@ -189,6 +201,15 @@ _ARITY = {
     "reduce_scatter": 1,
     "kv_cache_read": 1,
     "kv_cache_append": 3,
+    "rms_norm": 1,
+    "rope": 3,
+    "silu_mul": 1,
+    "moe_route": 1,
+    "moe_group_sizes": 1,
+    "moe_dispatch": 2,
+    "moe_combine": 3,
+    "grouped_dense": 3,
+    "generalized_grouped_dense": 3,
 }
 
 #: required attribute keys per op (checked before the transfer runs).
@@ -206,6 +227,13 @@ _REQUIRED_ATTRS = {
     "all_gather": ("group", "rank", "parts", "axis"),
     "all_reduce": ("group", "rank", "parts", "axis"),
     "reduce_scatter": ("group", "rank", "parts", "axis"),
+    "rms_norm": ("in_scale", "out_scale", "eps"),
+    "rope": ("in_scale", "out_scale"),
+    "silu_mul": ("in_scale", "out_scale"),
+    "moe_route": ("top_k",),
+    "moe_group_sizes": ("n_experts",),
+    "moe_combine": ("logit_scale", "scale"),
+    "generalized_grouped_dense": ("requant_scale", "clip_lo", "clip_hi"),
 }
 
 
@@ -247,6 +275,46 @@ def _dense_transfer(x, w, attrs) -> tuple[tuple[int, ...] | None, list[str]]:
             ]
         return (*x[:-1], k), []
     return None, [f"dense weight must be 2-D or 3-D, got {list(w)}"]
+
+
+def _moe_transfer(n: "ir.Node") -> tuple[tuple[int, ...] | None, list[str]]:
+    """Expected output shape of a routed-expert op (``ir.MOE_HOST_OPS``) or a
+    grouped dense, re-derived from its operands."""
+    ins = [i.shape for i in n.inputs]
+    op, a = n.op, n.attrs
+    if op in ("rms_norm", "rope"):
+        if op == "rope" and not (ins[1] == ins[2] == tuple(ins[0][-2:])):
+            return None, [f"rope tables {list(ins[1])}/{list(ins[2])} for {list(ins[0])}"]
+        return tuple(ins[0]), []
+    if op == "silu_mul":
+        if not ins[0] or ins[0][-1] % 2:
+            return None, [f"silu_mul needs an even last dim, got {list(ins[0])}"]
+        return (*ins[0][:-1], ins[0][-1] // 2), []
+    if op == "moe_route":
+        if len(ins[0]) != 2 or not 0 < a["top_k"] <= ins[0][1]:
+            return None, [f"top_k {a['top_k']} over logits {list(ins[0])}"]
+        return (ins[0][0], a["top_k"]), []
+    if op == "moe_group_sizes":
+        return (a["n_experts"],), []
+    if op == "moe_dispatch":
+        x, idx = ins
+        if len(x) != 2 or len(idx) != 2 or x[0] != idx[0]:
+            return None, [f"dispatch of {list(x)} by routes {list(idx)}"]
+        return (idx[0] * idx[1], x[1]), []
+    if op == "moe_combine":
+        y, idx, logits = ins
+        if len(idx) != 2 or y[0] != idx[0] * idx[1] or logits[0] != idx[0]:
+            return None, [f"combine of {list(y)} by routes {list(idx)}, logits {list(logits)}"]
+        return (idx[0], y[1]), []
+    x, w, gs = ins  # grouped dense
+    errs = []
+    if len(x) != 2 or len(w) != 3 or x[1] != w[1]:
+        errs.append(f"grouped dense contraction mismatch {list(x)} @ {list(w)}")
+    elif gs != (w[0],):
+        errs.append(f"group sizes {list(gs)} for {w[0]} groups")
+    if n.inputs[0].dtype != n.inputs[1].dtype:
+        errs.append(f"operand dtypes differ: {n.inputs[0].dtype} vs {n.inputs[1].dtype}")
+    return (None, errs) if errs else ((x[0], w[2]), [])
 
 
 def _conv_transfer(x, w, attrs) -> tuple[tuple[int, ...] | None, list[str]]:
@@ -559,6 +627,8 @@ class _GraphChecker:
                     f"pos shape {list(pos.shape)} must be scalar or the "
                     f"cache's leading dims {list(cache.shape[:-2])}"
                 )
+        elif op in ir.MOE_HOST_OPS or op in ir.GROUPED_OPS:
+            expected, errs = _moe_transfer(n)
         if errs:
             for e in errs:
                 self.diag("G_SHAPE", n, e)
@@ -621,6 +691,10 @@ class _GraphChecker:
                 n,
                 f"declared dtype {n.dtype} != operand dtype {x.dtype} "
                 f"({n.op} preserves its operand's dtype)",
+            )
+        elif n.op in _FIXED_DTYPE and n.dtype != _FIXED_DTYPE[n.op]:
+            self.diag(
+                "G_DTYPE", n, f"{n.op} must produce {_FIXED_DTYPE[n.op]}, not {n.dtype}"
             )
         elif n.op == "dequantize" and n.dtype != "float32":
             self.diag("G_DTYPE", n, f"dequantize must produce float32, not {n.dtype}")

@@ -5,8 +5,11 @@ the jaxpr, translating each equation into ``repro.core.ir`` nodes.  Two kinds
 of translation cooperate:
 
 * **direct primitives** map 1:1 onto IR ops — ``dot_general`` -> ``dense``,
-  ``conv_general_dilated`` -> ``conv2d``, ``transpose``/``reshape``,
-  ``reduce_window_max`` -> ``max_pool2d``, elementwise ``add``/``sub``/``mul``;
+  ``ragged_dot_general`` -> ``grouped_dense``, ``conv_general_dilated`` ->
+  ``conv2d``, ``transpose``/``reshape``, ``reduce_window_max`` ->
+  ``max_pool2d``, elementwise ``add``/``sub``/``mul``, and the named
+  ``jit`` calls of ``frontend.nn`` (the KV-cache ops and the routed-expert
+  block's host ops, their float scalars read from the call's literals);
 
 * **idiom patterns** recognize the multi-equation chains plain jnp produces
   for ops the IR models as one node: ``jnp.clip(jnp.round(x / s), -128, 127)
@@ -57,7 +60,12 @@ SUPPORTED_PRIMITIVES: dict[str, str] = {
     "mul": "mul / dequantize (astype-float * scale idiom)",
     "max": "relu (maximum(x, 0) idiom)",
     "custom_jvp_call": "(inlined: jax.nn.relu, ...)",
-    "jit": "(named: relu / clip / round / kv_cache_read / kv_cache_append; others inlined)",
+    "jit": (
+        "(named: relu / clip / round / kv_cache_read / kv_cache_append / rms_norm / "
+        "rope / silu_mul / moe_route / moe_group_sizes / moe_dispatch / moe_combine; "
+        "others inlined)"
+    ),
+    "ragged_dot_general": "grouped_dense (jax.lax.ragged_dot: rows sorted by group)",
     "convert_element_type": "quantize / requantize chain sinks",
     "div": "quantize interior (round(x / scale) idiom)",
     "round": "quantize / requantize interior",
@@ -350,6 +358,8 @@ class _Importer:
             return self.inline(eqn.params["call_jaxpr"], args)
         if prim == "dot_general":
             return [self.dot_general(eqn, args)]
+        if prim == "ragged_dot_general":
+            return [self.ragged_dot(eqn, args)]
         if prim == "conv_general_dilated":
             return [self.conv(eqn, args)]
         if prim == "transpose":
@@ -418,6 +428,9 @@ class _Importer:
         if name == "kv_cache_append" and len(args) == 3:
             cache, update, pos = (self.realize(a) for a in args)
             return [ir.kv_cache_append(cache, update, pos)]
+        node = self.named_moe(name, args, shape)
+        if node is not None:
+            return [node]
         if name == "round":
             return [_Pending("round", args, {}, shape, dtype)]
         if name == "clip" and len(args) == 3 and _is_lit(args[1]) and _is_lit(args[2]):
@@ -428,6 +441,50 @@ class _Importer:
             as_py = int if node.dtype.startswith(("int", "uint")) else float
             return [ir.clip(node, lo=as_py(_scalar(lo)), hi=as_py(_scalar(hi)))]
         return self.inline(closed, args)
+
+    def named_moe(self, name: str, args: list, shape: tuple) -> ir.Node | None:
+        """The named calls of ``frontend.nn``'s routed-expert block: tensor
+        operands first, then scalar literals (the op's attrs); sizes that
+        are static in the call (``top_k``, ``n_experts``) are read from its
+        result's shape.  None for any other name or form."""
+        arity = {"rms_norm": (1, 3), "rope": (3, 2), "silu_mul": (1, 2),
+                 "moe_route": (1, 0), "moe_group_sizes": (1, 0),
+                 "moe_dispatch": (2, 0), "moe_combine": (3, 2)}.get(name)
+        if arity is None or len(args) != sum(arity):
+            return None
+        tensors, lits = args[: arity[0]], args[arity[0]:]
+        if not all(_is_lit(v) for v in lits):
+            raise ValueError(f"{name}: scalar arguments must be literals")
+        x, *rest = (self.realize(t) for t in tensors)
+        f = [_scalar(v) for v in lits]
+        if name == "rms_norm":
+            return ir.rms_norm(x, in_scale=f[0], out_scale=f[1], eps=f[2])
+        if name == "rope":
+            return ir.rope(x, *rest, in_scale=f[0], out_scale=f[1])
+        if name == "silu_mul":
+            return ir.silu_mul(x, in_scale=f[0], out_scale=f[1])
+        if name == "moe_route":
+            return ir.moe_route(x, top_k=shape[-1])
+        if name == "moe_group_sizes":
+            return ir.moe_group_sizes(x, n_experts=shape[0])
+        if name == "moe_dispatch":
+            return ir.moe_dispatch(x, *rest)
+        return ir.moe_combine(x, *rest, logit_scale=f[0], scale=f[1])
+
+    def ragged_dot(self, eqn, args) -> ir.Node:
+        """``jax.lax.ragged_dot(x[R, C], w[G, C, K], group_sizes[G])``: rows
+        sorted by group, the ragged dimension the rows of ``x``."""
+        p = eqn.params
+        dn = p["ragged_dot_dimension_numbers"]
+        if (
+            dn.dot_dimension_numbers != (((1,), (1,)), ((), ()))
+            or tuple(dn.lhs_ragged_dimensions) != (0,)
+            or tuple(dn.rhs_group_dimensions) != (0,)
+            or p.get("group_offset") is not None
+        ):
+            raise ValueError(f"only ragged_dot over the rows of x: {dn}")
+        x, w, gs = (self.realize(a) for a in args)
+        return ir.grouped_dense(x, w, gs, out_dtype=str(eqn.outvars[0].aval.dtype))
 
     def inline(self, closed_jaxpr, args) -> list:
         jaxpr = closed_jaxpr.jaxpr

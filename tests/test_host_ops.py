@@ -84,6 +84,30 @@ CASES = [
           ),
           {"x": _i8(16, 8), "u": _i8(1, 8),
            "pos": np.asarray(5, np.int32)}),
+    _case("rms_norm",
+          lambda: ir.rms_norm(x_i8(), in_scale=0.0625, out_scale=0.03125, eps=1e-5),
+          {"x": _i8(3, 8)}),
+    _case("rope",
+          lambda: ir.rope(x_i8(), ir.input_((3, 8), "float32", name="c"),
+                          ir.input_((3, 8), "float32", name="s"),
+                          in_scale=0.0625, out_scale=0.0441941738),
+          {"x": _i8(3, 8), "c": np.cos(_f32(3, 8)), "s": np.sin(_f32(3, 8))}),
+    _case("silu_mul", lambda: ir.silu_mul(x_i8(), in_scale=0.0625, out_scale=0.0625),
+          {"x": _i8(3, 8)}),
+    _case("moe_route", lambda: ir.moe_route(x_i32(), top_k=2), {"x": _i32(3, 8)}),
+    _case("moe_group_sizes",
+          lambda: ir.moe_group_sizes(ir.input_((3, 2), "int32", name="x"), n_experts=4),
+          {"x": np.array([[0, 3], [3, 1], [0, 3]], np.int32)}),
+    _case("moe_dispatch",
+          lambda: ir.moe_dispatch(x_i8(), ir.input_((3, 2), "int32", name="i")),
+          {"x": _i8(3, 8), "i": np.array([[2, 0], [0, 1], [1, 2]], np.int32)}),
+    _case("moe_combine",
+          lambda: ir.moe_combine(ir.input_((6, 8), "int8", name="y"),
+                                 ir.input_((3, 2), "int32", name="i"),
+                                 ir.input_((3, 4), "int32", name="l"),
+                                 logit_scale=2.0**-4, scale=1.0),
+          {"y": _i8(6, 8), "i": np.array([[2, 0], [0, 1], [1, 3]], np.int32),
+           "l": _i32(3, 4)}),
 ]
 
 

@@ -165,3 +165,44 @@ def test_attention_epilogue_at_prefill512_compiles_for_v5e(one_chip, no_compile_
     out, flags, rows = compiled.out_info
     assert out.shape == shape and out.dtype == jnp.int8
     assert flags.shape == shape[:-1] and rows.shape == (48 * 512 // 16, 512)
+
+
+# (rows routed, K, N) of Mixtral-8x7B's two expert projections at 2 x 512
+# tokens, top-2 of 8 experts: gate|up side by side, then down
+MIXTRAL_EXPERT_GEMMS = [(2048, 4096, 2 * 14336), (2048, 14336, 4096)]
+
+
+@pytest.mark.parametrize("rows,k,n", MIXTRAL_EXPERT_GEMMS, ids=["gate_up", "down"])
+def test_grouped_expert_gemm_at_mixtral_width_compiles_for_v5e(
+    rows, k, n, one_chip, no_compile_cache
+):
+    """The grouped int8 kernel as the plan binds it for ``tpu_v5e``: the
+    schedule's column tile and the grouped row tile, whole-K weight panels
+    that fit the kernel's VMEM, eight experts, one launch."""
+    from repro.core import ir
+    from repro.core.strategy import workload_from_node
+    from repro.kernels import grouped
+
+    desc = make_tpu_v5e_description()
+    backend = build_backend(desc)
+    x = ir.input_((rows, k), "int8", name="x")
+    w = ir.input_((8, k, n), "int8", name="w")
+    sizes = ir.input_((8,), "int32", name="sizes")
+    node = ir.Node("generalized_grouped_dense", [x, w, sizes],
+                   {"quantized": True, "requant_scale": 2.0**-8, "clip_lo": -128,
+                    "clip_hi": 127}, shape=(rows, n), dtype="int8")
+    sched = backend.scheduler.schedule(workload_from_node(node))
+    strat = backend.strategy_gen.generate(node, sched)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(lowering, "pallas_interpret_mode", lambda: False)
+        cfg = lowering.grouped_kernel_config(desc, backend.mapping_gen, node, strat)
+    assert cfg.block_k == k and n % cfg.block_n == 0 and cfg.block_m % 32 == 0
+    args = [
+        jax.ShapeDtypeStruct((rows, k), jnp.int8, sharding=one_chip),
+        jax.ShapeDtypeStruct((8, k, n), jnp.int8, sharding=one_chip),
+        jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip),
+    ]
+    compiled = _compile_for_chip(
+        lambda x, w, g: grouped.grouped_qmatmul(x, w, g, cfg=cfg), args
+    )
+    assert compiled.out_info.shape == (rows, n)

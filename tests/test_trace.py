@@ -78,6 +78,9 @@ def _per_execution(bucket: int) -> dict[str, int]:
         "kernel_launches": len(layers),
         "attn_epilogue_rows": 0,
         "attn_fallback_rows": 0,
+        "expert_rows": 0,
+        "expert_pad_rows": 0,
+        "expert_rows_peak": 0,
     }
 
 

@@ -160,7 +160,12 @@ def compile_host_op(n: Node) -> Callable[..., np.ndarray]:
         return lambda x, cos, sin: rope_ref(x, cos, sin, s_in, s_out)
     if op == "silu_mul":
         s_in, s_out = attrs["in_scale"], attrs["out_scale"]
-        return lambda x: silu_mul_ref(x, s_in, s_out)
+
+        def _silu_mul(x):
+            trace.count_swiglu_rows(0, math.prod(x.shape[:-1]))
+            return silu_mul_ref(x, s_in, s_out)
+
+        return _silu_mul
     if op == "moe_route":
         top_k = attrs["top_k"]
         return lambda logits: moe_route_ref(logits, top_k)
@@ -460,12 +465,64 @@ def _attn_epilogues(
     return fused
 
 
+def _swiglu_epilogues(
+    graph: Graph, order: list[Node], ops: dict[Node, CompiledOp]
+) -> dict[Node, tuple[Callable, Node]]:
+    """The SiLU·mul steps the plan runs on the device.
+
+    A grouped GEMM whose executor offers ``fuse_silu_mul`` (a Pallas step,
+    see ``lowering._swiglu_step``) and whose sole consumer is an int8
+    ``silu_mul``, neither a graph output, becomes one step.  When the
+    ``silu_mul``'s sole consumer is such a grouped step too, reading it as
+    its rows, the fused step leaves its result on the device.  Maps the
+    GEMM to (its fused step fn, the ``silu_mul``)."""
+    consumers: dict[Node, list[Node]] = {}
+    for n in order:
+        for i in n.inputs:
+            if i is not None:
+                consumers.setdefault(i, []).append(n)
+    outputs = set(graph.outputs)
+
+    def fuse_of(n: Node):
+        return getattr(ops[n].executor, "fuse_silu_mul", None) if n in ops else None
+
+    patches: dict = {}  # one per pair of scales
+    fused = {}
+    for n in order:
+        fuse = fuse_of(n)
+        users = consumers.get(n, ())
+        if fuse is None or n.dtype != "int8" or n in outputs or len(users) != 1:
+            continue
+        silu = users[0]
+        if silu.op != "silu_mul" or silu.dtype != "int8" or silu in outputs:
+            continue
+        # a consumer appears once per input it reads the silu_mul as
+        nxt = consumers.get(silu, ())
+        keep = len(nxt) == 1 and fuse_of(nxt[0]) is not None and nxt[0].inputs[0] is silu
+        consts = {
+            i: inp.value
+            for i, inp in enumerate(n.inputs)
+            if inp is not None and inp.is_const()
+        }
+        fn = fuse(
+            in_scale=silu.attrs["in_scale"],
+            out_scale=silu.attrs["out_scale"],
+            consts=consts,
+            keep_on_device=keep,
+            patches=patches,
+        )
+        fused[n] = (fn, silu)
+    return fused
+
+
 def build_plan(graph: Graph, ops: dict[Node, CompiledOp]) -> ExecutionPlan:
     """Lower a compiled graph to its execution plan (one toposort, ever).
 
     An attention-score epilogue after a Pallas scores GEMM
     (``_attn_epilogues``) becomes one ``attn_scores`` step that writes the
-    chain's int8 result; the chain's host steps are not planned."""
+    chain's int8 result; the chain's host steps are not planned.  So does a
+    SiLU·mul after a Pallas grouped GEMM (``_swiglu_epilogues``): one
+    ``swiglu`` step that writes the ``silu_mul``'s slot."""
     order = graph.toposort()
     slot_of: dict[Node, int] = {n: i + 1 for i, n in enumerate(order)}
     input_slots: list[tuple[str, int]] = []
@@ -473,6 +530,8 @@ def build_plan(graph: Graph, ops: dict[Node, CompiledOp]) -> ExecutionPlan:
     steps: list[PlanStep] = []
     fused = _attn_epilogues(graph, order, ops)
     absorbed = {c for _, _, chain in fused.values() for c in chain}
+    swiglu = _swiglu_epilogues(graph, order, ops)
+    absorbed.update(silu for _, silu in swiglu.values())
     for n in order:
         slot = slot_of[n]
         if n.op == "input":
@@ -489,6 +548,12 @@ def build_plan(graph: Graph, ops: dict[Node, CompiledOp]) -> ExecutionPlan:
                 fn, last, _ = fused[n]
                 steps.append(
                     PlanStep(slot_of[last], fn, arg_slots, "attn_scores", n.name, "accel")
+                )
+                continue
+            if n in swiglu:
+                fn, silu = swiglu[n]
+                steps.append(
+                    PlanStep(slot_of[silu], fn, arg_slots, "swiglu", n.name, "accel")
                 )
                 continue
             if n in ops:

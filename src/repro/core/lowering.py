@@ -45,7 +45,7 @@ import numpy as np
 from repro.core import trace
 from repro.core.accel import AcceleratorDescription
 from repro.core.intrinsics import HardwareIntrinsicGenerator
-from repro.core.ir import Node, execute_node, gelu_ref, max_pool2d_ref
+from repro.core.ir import Node, execute_node, gelu_ref, max_pool2d_ref, silu_mul_ref
 from repro.core.mapping import MappingGenerator
 from repro.core.scheduler import grouped_block_m
 from repro.core.strategy import Strategy
@@ -597,9 +597,11 @@ def _make_grouped_executor(
     specializes the step over its constant expert weights
     (``specialize_consts``): they are uploaded by the first call and stay
     on the device (``_Resident``), so a call moves only the routed rows, the
-    group sizes and the result.  Counts ``expert_rows``, ``expert_pad_rows``
-    and ``expert_rows_peak`` (``repro.core.trace``).  Off the Pallas path,
-    or unquantized, the step is the IR's reference."""
+    group sizes and the result.  Rows already on the device (a
+    ``jax.Array``) move nothing.  Counts ``expert_rows``,
+    ``expert_pad_rows`` and ``expert_rows_peak`` (``repro.core.trace``).
+    The step offers ``fuse_silu_mul`` (``_swiglu_step``).  Off the Pallas
+    path, or unquantized, the step is the IR's reference."""
     from repro.kernels import grouped as kgrouped
 
     if not (pallas and resolved_fused_epilogue(node, strategy)):
@@ -610,7 +612,8 @@ def _make_grouped_executor(
     def run_kernel(x_j, w_j, gs_j):
         return kgrouped.grouped_qmatmul(x_j, w_j, gs_j, cfg=cfg)
 
-    def run(on, x, w_j, group_sizes):
+    def device_rows(on, x, w_j, group_sizes):
+        """The kernel's result, left on the device."""
         gs = np.asarray(group_sizes)
         _, pad, peak = kgrouped.tile_layout(gs, cfg.block_m)
         trace.count_expert_rows(int(gs.sum()), pad, n_groups * peak)
@@ -618,30 +621,169 @@ def _make_grouped_executor(
         trace.count_launch()
         if on:
             with trace.TraceAnnotation("repro.launch"):
-                out = run_kernel(x_j, w_j, gs_j)
-        else:
-            out = run_kernel(x_j, w_j, gs_j)
-        return _to_host(out, on)
+                return run_kernel(x_j, w_j, gs_j)
+        return run_kernel(x_j, w_j, gs_j)
+
+    def weights(consts: dict[int, np.ndarray]) -> Callable:
+        """``(on, w) -> w`` on the device: a constant panel's resident copy,
+        else ``w`` uploaded."""
+        if 1 not in consts:
+            return lambda on, w: _to_device(w, on)
+        resident = _Resident(np.asarray(consts[1]))
+        return lambda on, w: resident.get(on)
 
     def grouped_exec(x, w, group_sizes):
         on = trace.enabled()
-        return run(on, x, _to_device(w, on), group_sizes)
+        return _to_host(device_rows(on, x, _to_device(w, on), group_sizes), on)
 
     def specialize_consts(consts: dict[int, np.ndarray]):
         if 1 not in consts:
             return None
-        resident = _Resident(np.asarray(consts[1]))
+        weights_on = weights(consts)
 
         def grouped_resident(x, w, group_sizes):
             on = trace.enabled()
-            return run(on, x, resident.get(on), group_sizes)
+            return _to_host(device_rows(on, x, weights_on(on, w), group_sizes), on)
 
         return grouped_resident
 
     grouped_exec.kernel_config = cfg
     grouped_exec.run_kernel = run_kernel
     grouped_exec.specialize_consts = specialize_consts
+    grouped_exec.fuse_silu_mul = functools.partial(
+        _swiglu_step, node, device_rows, weights
+    )
     return grouped_exec
+
+
+# ---------------------------------------------------------------------------
+# SwiGLU (SiLU·mul) on the device after the gate|up grouped GEMM
+# ---------------------------------------------------------------------------
+
+
+#: The device's SiLU·mul is float32 (``_swiglu_q``), the host's float64
+#: (``ir.silu_mul_ref``).  Each element is a function of its own int8
+#: (gate, up) pair alone, so the host's bits are a 256 x 256 table
+#: (``swiglu_table``), and the plan checks the device against all of it
+#: once: it runs ``_swiglu_q`` over every pair on the device, and every
+#: pair whose rounded float32 value is not the table's, or lies within
+#: ``SWIGLU_TOL * max(|q|, 1)`` of a half (or is not finite), joins the
+#: step's patch, which takes the table's value for that pair on every
+#: call.  Every other pair's float32 value lies that far from a half and
+#: rounds to the table's value, so a call's value of it rounds the same
+#: unless it strays by 256 float32 ulp from the check's (the same
+#: operations on the same pair; a TPU v5e's float32 value of a Mixtral
+#: pair errs from the float64 one by at most 63 ulp, PERF.md).  Values
+#: past the int8 range saturate alike and are never near.  Some
+#: ``2 * SWIGLU_TOL * |q|`` of the pairs inside the range lie that near a
+#: half, at most about 0.4%, so the patch stays short: about ten pairs at
+#: Mixtral's scales.  A gather from the table on the device (XLA's, 237 ms
+#: a layer on a TPU v5e, PERF.md) would cost more than the host.
+SWIGLU_TOL = 2.0**-16
+
+
+def _pair_rows() -> np.ndarray:
+    """Every int8 (gate, up) pair as ``[gate | up]`` rows: int8 ``[256, 512]``,
+    ``rows[i, :256] = i - 128``, ``rows[i, 256:] = arange(-128, 128)``; the
+    pair of element ``(i, j)`` has the code ``i * 256 + j``."""
+    codes = np.arange(-128, 128, dtype=np.int8)
+    return np.concatenate(
+        [np.repeat(codes[:, None], 256, axis=1), np.tile(codes, (256, 1))], axis=1
+    )
+
+
+def swiglu_table(in_scale: float, out_scale: float) -> np.ndarray:
+    """``silu_mul_ref`` of every int8 (gate, up) pair, flat: the entry
+    ``(gate + 128) * 256 + (up + 128)``."""
+    return silu_mul_ref(_pair_rows(), in_scale, out_scale).reshape(-1)
+
+
+def _swiglu_q(rows, in_scale: float, out_scale: float):
+    """``silu(gate) * up / out_scale`` of the int8 ``[R, 2F]`` gate|up rows,
+    in float32, in ``silu_mul_ref``'s order."""
+    f = rows.shape[-1] // 2
+    g = rows[:, :f].astype(jnp.float32) * jnp.float32(in_scale)
+    u = rows[:, f:].astype(jnp.float32) * jnp.float32(in_scale)
+    return g / (1.0 + jnp.exp(-g)) * u / jnp.float32(out_scale)
+
+
+def _to_int8(q):
+    return jnp.clip(jnp.round(q), -128, 127).astype(jnp.int8)
+
+
+@functools.partial(jax.jit, static_argnames=("in_scale", "out_scale"))
+def _swiglu_check(rows, *, in_scale, out_scale):
+    """The device's int8 SiLU·mul of ``rows`` and where its float32 value
+    lies within ``SWIGLU_TOL`` of a half."""
+    q = _swiglu_q(rows, in_scale, out_scale)
+    a = jnp.abs(q)
+    near = (jnp.abs(jnp.abs(q - jnp.floor(q)) - 0.5) < SWIGLU_TOL * jnp.maximum(a, 1.0)) & (
+        a < 129.0
+    )
+    return _to_int8(q), near | ~jnp.isfinite(q)
+
+
+@functools.partial(jax.jit, static_argnames=("in_scale", "out_scale", "patch"))
+def _swiglu_device(rows, *, in_scale, out_scale, patch):
+    """``silu_mul`` of the int8 ``[R, 2F]`` gate|up rows: int8 ``[R, F]``,
+    ``patch`` the ``(code, value)`` of each pair the table decides."""
+    out = _to_int8(_swiglu_q(rows, in_scale, out_scale))
+    if patch:
+        f = rows.shape[-1] // 2
+        code = (rows[:, :f].astype(jnp.int32) + 128) * 256 + rows[:, f:].astype(
+            jnp.int32
+        ) + 128
+        for c, value in patch:
+            out = jnp.where(code == c, jnp.int8(value), out)
+    return out
+
+
+def swiglu_patch(in_scale: float, out_scale: float) -> tuple[tuple[int, int], ...]:
+    """The ``(code, table value)`` of every pair whose device value does not
+    give the table's bits with room to spare (``SWIGLU_TOL``): checked on
+    the device over all 65,536 pairs."""
+    table = swiglu_table(in_scale, out_scale)
+    out, near = _swiglu_check(
+        jnp.asarray(_pair_rows()), in_scale=in_scale, out_scale=out_scale
+    )
+    bad = np.asarray(near).reshape(-1) | (np.asarray(out).reshape(-1) != table)
+    return tuple((int(c), int(table[c])) for c in np.flatnonzero(bad))
+
+
+def _swiglu_step(
+    node: Node,
+    device_rows: Callable,
+    weights: Callable,
+    *,
+    in_scale: float,
+    out_scale: float,
+    consts: dict[int, np.ndarray],
+    keep_on_device: bool,
+    patches: dict,
+) -> Callable:
+    """One accelerator step for ``silu_mul(grouped_dense(x, w, sizes))``
+    over the gate|up grouped GEMM ``node``: the kernel as the plain step
+    runs it, then ``_swiglu_device`` with the scales' patch
+    (``SWIGLU_TOL``); the gate|up rows never leave the device.  With
+    ``keep_on_device`` (the next step is a grouped GEMM that takes rows on
+    the device) the ``[R, F]`` result stays there too, as a ``jax.Array``;
+    otherwise it is synced.  ``patches`` holds one patch per pair of
+    scales."""
+    key = (in_scale, out_scale)
+    if key not in patches:
+        patches[key] = swiglu_patch(in_scale, out_scale)
+    patch = patches[key]
+    weights_on = weights(consts)
+    n_rows = node.shape[0]
+
+    def swiglu(x, w, group_sizes):
+        on = trace.enabled()
+        rows = device_rows(on, x, weights_on(on, w), group_sizes)
+        out = _swiglu_device(rows, in_scale=in_scale, out_scale=out_scale, patch=patch)
+        trace.count_swiglu_rows(n_rows, 0)
+        return out if keep_on_device else _to_host(out, on)
+
+    return swiglu
 
 
 # ---------------------------------------------------------------------------

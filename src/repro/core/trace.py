@@ -29,7 +29,10 @@ Span names (docs/architecture.md, "Observability"):
 * ``repro.accel.generalized_grouped_dense`` — one grouped expert GEMM (all
   experts, one launch); ``repro.host.rms_norm``, ``.rope``, ``.silu_mul``,
   ``.moe_route``, ``.moe_group_sizes``, ``.moe_dispatch``,
-  ``.moe_combine`` — the host steps of a routed-expert block.
+  ``.moe_combine`` — the host steps of a routed-expert block;
+* ``repro.accel.swiglu`` — the step that runs a gate|up grouped expert
+  GEMM and the SiLU·mul after it on the device, exact against the host's
+  table (``lowering._swiglu_step``).
 
 Counters: ``h2d_transfers`` / ``h2d_bytes``, ``d2h_syncs`` / ``d2h_bytes``,
 ``kernel_launches``; ``attn_epilogue_rows`` — rows of scores the device
@@ -39,7 +42,9 @@ expert GEMMs; ``expert_pad_rows`` — rows those GEMMs computed beyond the
 routed ones, from row-tile padding at expert boundaries;
 ``expert_rows_peak`` — per grouped GEMM, the number of experts times the
 busiest expert's rows (``expert_rows_peak / expert_rows`` is the routing's
-imbalance: 1 when every expert gets as many rows).
+imbalance: 1 when every expert gets as many rows); ``swiglu_device_rows``
+— rows of SiLU·mul the fused ``swiglu`` step computed on the device;
+``swiglu_host_rows`` — rows the host ``silu_mul`` step computed.
 """
 
 from __future__ import annotations
@@ -61,6 +66,8 @@ COUNTERS = (
     "expert_rows",
     "expert_pad_rows",
     "expert_rows_peak",
+    "swiglu_device_rows",
+    "swiglu_host_rows",
 )
 
 _NULL = contextlib.nullcontext()
@@ -120,6 +127,12 @@ def count_expert_rows(rows: int, pad: int, peak: int) -> None:
         _counts["expert_rows"] += rows
         _counts["expert_pad_rows"] += pad
         _counts["expert_rows_peak"] += peak
+
+
+def count_swiglu_rows(device: int, host: int) -> None:
+    with _lock:
+        _counts["swiglu_device_rows"] += device
+        _counts["swiglu_host_rows"] += host
 
 
 def snapshot() -> dict[str, int]:

@@ -6,6 +6,7 @@ benchmark's plain reference, exactly."""
 
 from __future__ import annotations
 
+import functools
 import json
 import sys
 from pathlib import Path
@@ -16,9 +17,9 @@ import numpy as np
 import pytest
 
 import repro
-from repro.core import ir, trace
+from repro.core import executor, ir, lowering, trace
 from repro.core.scheduler import grouped_block_m
-from repro.core.verify import verify_graph
+from repro.core.verify import verify_graph, verify_plan
 from repro.frontend import nn as fnn
 from repro.frontend.importer import SUPPORTED_PRIMITIVES, trace_model
 from repro.kernels import grouped as kgrouped
@@ -342,6 +343,9 @@ def test_mixtral_stack_matches_the_reference_exactly(mixtral):
     # per layer: q, k, v, o, the router, 2 x 2 scores and 2 x 2 context
     # instances, two grouped launches
     assert counted["kernel_launches"] == 2 * (5 + 4 + 4 + 2)
+    # SiLU-mul on the device over each layer's routed rows, none on the host
+    assert counted["swiglu_device_rows"] == 2 * 64
+    assert counted["swiglu_host_rows"] == 0
 
 
 def test_expert_weights_stay_on_the_device_after_the_first_call(mixtral):
@@ -362,11 +366,156 @@ def test_mixtral_plan_has_one_grouped_step_per_expert_projection(mixtral):
     _, _, _, _, _, module = mixtral
     plan = module.modules[2].plan
     ops = [s.op for s in plan.steps]
-    assert ops.count("generalized_grouped_dense") == 2 * 2
+    # gate|up and SiLU-mul in one swiglu step, down a grouped step
+    assert ops.count("swiglu") == 2
+    assert ops.count("generalized_grouped_dense") == 2
+    assert "silu_mul" not in ops
     # q, k, v and o take the quantized chain without a bias
     assert ops.count("generalized_dense") == 2 * 4
     assert ops.count("attn_scores") == 2
-    for op in ("rms_norm", "rope", "silu_mul", "moe_route", "moe_combine"):
+    for op in ("rms_norm", "rope", "moe_route", "moe_combine"):
         assert op in ops
+    # arena def/use and the two-lane race detector over the fused plan
+    assert verify_plan(plan) == []
     cycles = module.modules[2].modeled_cycles()
     assert np.isfinite(cycles["total"]) and cycles["accel"] > 0 and cycles["host"] > 0
+
+
+# ---------------------------------------------------------------------------
+# SiLU-mul on the device, exact through the table
+# ---------------------------------------------------------------------------
+
+MIXTRAL_SCALES = (0.0625, 0.0625)
+
+
+def _all_pairs(scales):
+    """Every int8 (gate, up) pair once, in a random order, as rows of
+    ``[gate | up]``."""
+    pairs = RNG.permutation(65536)
+    gate = (pairs // 256 - 128).astype(np.int8).reshape(128, 512)
+    up = (pairs % 256 - 128).astype(np.int8).reshape(128, 512)
+    return np.concatenate([gate, up], axis=1), scales
+
+
+def _codes(rows):
+    """The pair code ``(gate + 128) * 256 + (up + 128)`` of each element."""
+    f = rows.shape[1] // 2
+    return (rows[:, :f].astype(np.int32) + 128) * 256 + rows[:, f:].astype(np.int32) + 128
+
+
+SWIGLU_CASES = {
+    "all_pairs_mixtral": lambda: _all_pairs(MIXTRAL_SCALES),
+    "all_pairs_odd_scales": lambda: _all_pairs((0.0473, 0.0391)),
+    "random_mixtral_width": lambda: (_i8(24, 2 * 14336), MIXTRAL_SCALES),
+}
+
+
+@pytest.mark.parametrize("case", SWIGLU_CASES.values(), ids=list(SWIGLU_CASES))
+def test_swiglu_table_and_device_form_give_the_bits_of_silu_mul_ref(case):
+    rows, (s_in, s_out) = case()
+    want = ir.silu_mul_ref(rows, s_in, s_out)
+    code = _codes(rows)
+    assert np.array_equal(lowering.swiglu_table(s_in, s_out)[code], want)
+    got = lowering._swiglu_device(jnp.asarray(rows), in_scale=s_in, out_scale=s_out,
+                                  patch=lowering.swiglu_patch(s_in, s_out))
+    assert got.dtype == jnp.int8 and np.array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("error, scales", [(0.0, MIXTRAL_SCALES), (2.0**-9, (0.0611, 0.0589))],
+                         ids=["this_device", "sloppy_device"])
+def test_swiglu_patch_holds_every_pair_the_float32_form_misses(error, scales, monkeypatch):
+    """The plan's check over all pairs patches each pair the device rounds
+    otherwise, here also for a device whose float32 value errs by 2^-9
+    (scales no other test traces, so the jitted forms trace it anew)."""
+    exact = lowering._swiglu_q
+    monkeypatch.setattr(lowering, "_swiglu_q",
+                        lambda rows, s_in, s_out: exact(rows, s_in, s_out) * (1 + error))
+    rows, (s_in, s_out) = _all_pairs(scales)
+    want = ir.silu_mul_ref(rows, s_in, s_out)
+    patch = lowering.swiglu_patch(s_in, s_out)
+    device = functools.partial(lowering._swiglu_device, jnp.asarray(rows), in_scale=s_in,
+                               out_scale=s_out)
+    code = _codes(rows)
+    missed = set(code[np.asarray(device(patch=())) != want].tolist())
+    assert missed <= {c for c, _ in patch}
+    assert np.array_equal(np.asarray(device(patch=patch)), want)
+    if error:
+        assert missed
+    else:  # a short patch: the pairs within SWIGLU_TOL of a half
+        assert 0 < len(patch) <= 64
+
+
+W_UP, W_DOWN = _i8(4, 16, 32), _i8(4, 16, 8)
+
+
+def _swiglu_graph(consumer: str) -> ir.Graph:
+    """Grouped gate|up -> requantize -> silu_mul -> ``consumer``: the down
+    grouped GEMM (requantized), or an int8 add of the silu_mul to itself."""
+    x = ir.input_((24, 16), "int8", name="x")
+    sizes = ir.input_((4,), "int32", name="sizes")
+    gu = ir.requantize(ir.grouped_dense(x, ir.const(W_UP, name="w_up"), sizes), 2.0**-6)
+    act = ir.silu_mul(gu, in_scale=0.0625, out_scale=0.0625)
+    if consumer == "down":
+        out = ir.requantize(ir.grouped_dense(act, ir.const(W_DOWN, name="w_down"), sizes),
+                            2.0**-6)
+    else:
+        out = ir.add(act, act)
+    return ir.Graph([out], name=f"swiglu_{consumer}")
+
+
+SWIGLU_FEEDS = {"x": _i8(24, 16), "sizes": np.array([5, 0, 12, 7], np.int32)}
+
+
+@pytest.mark.parametrize("consumer, on_device", [("down", True), ("add", False)])
+def test_swiglu_step_keeps_its_result_only_for_a_grouped_step(consumer, on_device):
+    module = repro.compile(_swiglu_graph(consumer), repro.Target("tpu_v5e", cache=False))
+    plan = module.plan
+    (step,) = [s for s in plan.steps if s.op == "swiglu"]
+    assert "silu_mul" not in [s.op for s in plan.steps]
+    arena = plan.new_arena()
+    before = trace.snapshot()
+    got = plan.execute(SWIGLU_FEEDS, arena)
+    counted = trace.since(before)
+    assert isinstance(arena[step.slot], jax.Array) is on_device
+    assert isinstance(arena[step.slot], np.ndarray) is not on_device
+    want = ir.execute_graph(_swiglu_graph(consumer), SWIGLU_FEEDS)
+    assert np.array_equal(np.asarray(got[0]), want[0])
+    assert (counted["swiglu_device_rows"], counted["swiglu_host_rows"]) == (24, 0)
+
+
+@pytest.mark.parametrize("target", ["gemmini", "edge_npu"])
+def test_emulated_target_keeps_silu_mul_on_the_host(target):
+    module = repro.compile(_swiglu_graph("down"), repro.Target(target, cache=False))
+    ops = [s.op for s in module.plan.steps]
+    assert "swiglu" not in ops and "silu_mul" in ops
+    before = trace.snapshot()
+    got = module.run(SWIGLU_FEEDS)
+    counted = trace.since(before)
+    assert np.array_equal(got[0], ir.execute_graph(_swiglu_graph("down"), SWIGLU_FEEDS)[0])
+    assert (counted["swiglu_device_rows"], counted["swiglu_host_rows"]) == (0, 24)
+
+
+def _bench_plan(name: str):
+    """The plan of a benchmark cell's bucket module at its test sizes."""
+    from bench import run, spec
+
+    cell = spec.load_cell(name)
+    cell.model.test_sizes(cell.config, cell.traffic)
+    shape = cell.model.sample_shape(cell.config, cell.traffic)
+    params = cell.model.make_params(cell.config, run.jax_key(3), shape)
+    buckets = tuple(cell.traffic["buckets"])
+    module = repro.compile(
+        cell.model.model_fn(cell.config), repro.Target("tpu_v5e", mode="optimized"),
+        example_inputs={"x": np.zeros(shape, np.int8)}, params=params,
+        options=repro.CompileOptions(batch_buckets=buckets),
+    )
+    return module.bucket_module(buckets[-1])
+
+
+@pytest.mark.parametrize("name", ["musicgen.prefill512", "toycar.stream"])
+def test_plans_without_experts_are_unchanged_by_the_swiglu_matcher(name, monkeypatch):
+    bucket = _bench_plan(name)
+    stages = bucket.plan.stage_assignment()
+    assert "swiglu" not in [s["op"] for s in stages]
+    monkeypatch.setattr(executor, "_swiglu_epilogues", lambda *a: {})
+    assert executor.build_plan(bucket.graph, bucket.ops).stage_assignment() == stages

@@ -5,8 +5,9 @@ describes but does not attach, and the TPU compiler (Mosaic included)
 accepts it or raises what the chip's compiler would raise — a block that
 does not tile, or more VMEM than the kernel asked for.  That covers ToyCar's
 accelerator steps, as a real ``tpu_v5e`` compile binds them, the
-scheduled GEMMs at widths where the scheduler's VMEM budget matters, and
-the device attention epilogue at MusicGen's width.
+scheduled GEMMs at widths where the scheduler's VMEM budget matters, the
+device attention epilogue at MusicGen's width, and Mixtral's grouped
+expert GEMMs and device SiLU·mul.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -206,3 +207,16 @@ def test_grouped_expert_gemm_at_mixtral_width_compiles_for_v5e(
         lambda x, w, g: grouped.grouped_qmatmul(x, w, g, cfg=cfg), args
     )
     assert compiled.out_info.shape == (rows, n)
+
+
+def test_swiglu_at_mixtral_width_compiles_for_v5e(one_chip, no_compile_cache):
+    """The device SiLU·mul of ``mixtral.prefill512``: the gate|up rows of one
+    layer's routed tokens in float32, and the patch of pairs the table
+    decides."""
+    rows = jax.ShapeDtypeStruct((2048, 2 * 14336), jnp.int8, sharding=one_chip)
+    compiled = lowering._swiglu_device.lower(
+        rows, in_scale=0.0625, out_scale=0.0625,
+        patch=lowering.swiglu_patch(0.0625, 0.0625),
+    ).compile()
+    assert compiled.out_info.shape == (2048, 14336)
+    assert compiled.out_info.dtype == jnp.int8

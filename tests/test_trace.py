@@ -81,6 +81,8 @@ def _per_execution(bucket: int) -> dict[str, int]:
         "expert_rows": 0,
         "expert_pad_rows": 0,
         "expert_rows_peak": 0,
+        "swiglu_device_rows": 0,
+        "swiglu_host_rows": 0,
     }
 
 

@@ -1,5 +1,4 @@
-"""Cold-start benchmark: AOT artifact load vs full compile, plus the
-pipelined (two-lane) executor vs the sequential plan loop.
+"""Cold-start benchmark: AOT artifact load vs full compile.
 
 Fleet question this answers: when N serving replicas boot the same model,
 what does each replica pay?  Two ways:
@@ -13,21 +12,14 @@ what does each replica pay?  Two ways:
 Correctness gates the timing: loaded modules must be bit-exact with the
 compiled ones, and the restored backend counters must read zero work.
 
-The second half times ``run_many(pipelined=True)`` against the sequential
-loop on host-op-heavy plans (the lanes actually overlap only with >= 2
-CPUs; on a single-CPU host the numbers are recorded but the overlap gate
-is skipped — flagged in the payload as ``can_overlap``).
-
 Results land in ``BENCH_coldstart.json``.  ``--smoke`` runs one cell (CI);
-``--gate`` enforces the cold-start speedup (and the overlap speedup when
-the host can overlap).
+``--gate`` enforces the cold-start speedup.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import platform
 import shutil
 import tempfile
@@ -42,22 +34,6 @@ from repro.core.zoo import get_model, model_names
 ACCELERATORS = ("gemmini", "edge_npu")
 SMOKE_MODELS = ("qcnn",)  # big enough that compile time dwarfs load time
 SMOKE_ACCELERATORS = ("gemmini",)
-
-#: host-op-heavy (model, accelerator, mode) plans for the pipelined-vs-
-#: sequential comparison — naive/baseline modes keep epilogues and layout
-#: ops on the host lane, which is what the second lane overlaps.
-PIPELINE_CELLS = (
-    ("qcnn", "gemmini", "baseline"),
-    ("toycar_mlp", "edge_npu", "naive"),
-)
-SMOKE_PIPELINE_CELLS = (("qcnn", "gemmini", "baseline"),)
-
-
-def _cpus() -> int:
-    try:
-        return len(os.sched_getaffinity(0))
-    except AttributeError:  # non-Linux
-        return os.cpu_count() or 1
 
 
 def _assert_zero_work(module) -> None:
@@ -114,58 +90,14 @@ def bench_coldstart_cell(model_name: str, acc: str, *, reps: int) -> dict:
     }
 
 
-def bench_pipeline_cell(
-    model_name: str, acc: str, mode: str, *, n_calls: int, reps: int
-) -> dict:
-    """Sequential plan loop vs two-lane pipelined execution of the same
-    traffic, gated on bit-exactness."""
-    model = get_model(model_name)
-    module = repro.compile(model_name, repro.Target(acc, mode=mode, cache=False))
-    sizes = module.finalize().lane_sizes()
-    traffic = [model.feeds(seed=s) for s in range(n_calls)]
-
-    seq_out = module.run_many(traffic)  # warmup + reference
-    pipe_out = module.run_many(traffic, pipelined=True)
-    for i, (a_row, b_row) in enumerate(zip(seq_out, pipe_out)):
-        for a, b in zip(a_row, b_row):
-            assert np.array_equal(a, b), (
-                f"{model_name}/{acc}/{mode}: pipelined output diverges at "
-                f"call {i}"
-            )
-
-    def best_of(fn) -> float:
-        best = float("inf")
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            fn()
-            best = min(best, time.perf_counter() - t0)
-        return max(best, 1e-9)
-
-    seq_s = best_of(lambda: module.run_many(traffic))
-    pipe_s = best_of(lambda: module.run_many(traffic, pipelined=True))
-    return {
-        "model": model_name,
-        "accelerator": acc,
-        "mode": mode,
-        "n_calls": n_calls,
-        "lane_sizes": sizes,
-        "sequential_ms": seq_s * 1e3,
-        "pipelined_ms": pipe_s * 1e3,
-        "overlap_speedup": seq_s / pipe_s,
-    }
-
-
 def run(
     models: list[str],
     accelerators: tuple[str, ...],
-    pipeline_cells,
     *,
     smoke: bool,
     gate: bool,
     out: Path,
 ) -> dict:
-    cpus = _cpus()
-    can_overlap = cpus > 1
     reps = 2 if smoke else 4
 
     rows = []
@@ -183,40 +115,20 @@ def run(
                 f"({row['load_speedup']:>5.1f}x)"
             )
 
-    pipe_rows = []
-    for name, acc, mode in pipeline_cells:
-        row = bench_pipeline_cell(
-            name, acc, mode, n_calls=8 if smoke else 64, reps=reps
-        )
-        pipe_rows.append(row)
-        print(
-            f"{row['model']:>18} {row['accelerator']:>8} {row['mode']:>9} "
-            f"seq={row['sequential_ms']:>8.2f} ms "
-            f"pipe={row['pipelined_ms']:>8.2f} ms "
-            f"({row['overlap_speedup']:>5.2f}x, lanes {row['lane_sizes']})"
-        )
-
     best = max(rows, key=lambda r: r["load_speedup"])
-    best_pipe = max(pipe_rows, key=lambda r: r["overlap_speedup"])
     payload = {
         "bench": "coldstart_artifact_vs_compile",
         "smoke": smoke,
         "host": platform.machine(),
-        "cpus": cpus,
-        "can_overlap": can_overlap,
         "rows": rows,
-        "pipeline_rows": pipe_rows,
         "summary": {
             "best_load_speedup": best["load_speedup"],
             "best_cell": (best["model"], best["accelerator"]),
-            "best_overlap_speedup": best_pipe["overlap_speedup"],
-            "best_overlap_cell": (best_pipe["model"], best_pipe["accelerator"]),
         },
     }
     out.write_text(json.dumps(payload, indent=2))
     print(
-        f"\nwrote {out} ({len(rows)} cold-start cells, {len(pipe_rows)} "
-        f"pipeline cells, {cpus} cpu(s)); best load speedup "
+        f"\nwrote {out} ({len(rows)} cold-start cells); best load speedup "
         f"{best['load_speedup']:.1f}x on {best['model']}/{best['accelerator']}"
     )
 
@@ -234,17 +146,6 @@ def run(
             f"(got {best['load_speedup']:.2f}x on "
             f"{best['model']}/{best['accelerator']})"
         )
-        if can_overlap:
-            assert best_pipe["overlap_speedup"] >= 1.02, (
-                f"pipelined execution must overlap host and accel lanes on "
-                f"a multi-CPU host (got {best_pipe['overlap_speedup']:.2f}x "
-                f"on {best_pipe['model']}/{best_pipe['accelerator']})"
-            )
-        else:
-            print(
-                "single-CPU host: overlap-speedup gate skipped "
-                "(lanes cannot run concurrently)"
-            )
     return payload
 
 
@@ -253,17 +154,16 @@ def main(argv: list[str] | None = None) -> dict:
     ap.add_argument("--smoke", action="store_true",
                     help="one cell with few reps (CI)")
     ap.add_argument("--gate", action="store_true",
-                    help="enforce cold-start (and, with >1 CPU, overlap) speedups")
+                    help="enforce the cold-start speedup")
     ap.add_argument("--models", nargs="*", default=None,
                     help=f"zoo models (default: all; available: {model_names()})")
     ap.add_argument("--out", type=Path, default=Path("BENCH_coldstart.json"))
     args = ap.parse_args(argv)
     models = args.models or list(SMOKE_MODELS if args.smoke else model_names())
     accelerators = SMOKE_ACCELERATORS if args.smoke else ACCELERATORS
-    cells = SMOKE_PIPELINE_CELLS if args.smoke else PIPELINE_CELLS
     for m in models:
         get_model(m)  # fail fast on typos
-    return run(models, accelerators, cells, smoke=args.smoke, gate=args.gate,
+    return run(models, accelerators, smoke=args.smoke, gate=args.gate,
                out=args.out)
 
 

@@ -7,16 +7,15 @@ For every (zoo model, accelerator, mode) cell this harness reports
     cycle model — the paper's Table-2 axis: proposed ~= C toolchain,
     naive BYOC blown up by unfolded preprocessing + unfused epilogues;
   * **wall-clock run latency** of the planned executor (``run_many``)
-    versus the legacy per-node interpreter over the same feeds — the
-    serving-path axis the planned executor adds.
+    over a batch of feeds.
 
-Functional correctness is asserted before any timing: the planned path
-must be bit-exact with the legacy interpreter in every cell, and with the
-graph reference semantics on the numpy-exact targets.
+Functional correctness is asserted before any timing: every cell must be
+bit-exact with the reference semantics of the hand-built golden graph
+(``ir.execute_graph``).
 
 Results are written to ``BENCH_table2.json``.  ``--smoke`` runs a reduced
-matrix with minimal reps (CI); the full run asserts the paper's cycle
-orderings and a >= 2x repeated-run speedup on at least one zoo model.
+matrix with minimal reps (CI); every run asserts the paper's cycle
+orderings.
 """
 
 from __future__ import annotations
@@ -33,11 +32,6 @@ import repro
 from repro.core import ir
 from repro.core.pipeline import PUBLIC_MODES
 from repro.core.zoo import get_model, model_names
-
-#: targets whose executors are pure numpy — bit-exact vs. the graph
-#: reference.  The TPU path computes through bf16/XLA for non-legalized
-#: ops, so it is held only to planned == legacy.
-NUMPY_EXACT = {"gemmini", "edge_npu"}
 
 SMOKE_MODELS = ("mlp_tiny", "qcnn")
 SMOKE_ACCELERATORS = {"gemmini", "edge_npu"}
@@ -61,21 +55,14 @@ def bench_cell(model, acc: str, mode: str, *, smoke: bool) -> dict:
 
     # -- correctness gate ---------------------------------------------------
     planned = mod.run(feeds)
-    legacy = mod.run(feeds, use_plan=False)
-    for p, leg in zip(planned, legacy):
-        assert np.array_equal(p, leg), (
-            f"{model.name}/{acc}/{mode}: planned executor "
-            f"diverges from the legacy interpreter"
+    # reference semantics come from the HAND-BUILT golden graph: this
+    # also pins traced-frontend parity on every benchmark run
+    ref = ir.execute_graph(model.build(), feeds)
+    for p, r in zip(planned, ref):
+        assert np.array_equal(np.asarray(p), r), (
+            f"{model.name}/{acc}/{mode}: executor diverges "
+            f"from golden graph reference semantics"
         )
-    if acc in NUMPY_EXACT:
-        # reference semantics come from the HAND-BUILT golden graph: this
-        # also pins traced-frontend parity on every benchmark run
-        ref = ir.execute_graph(model.build(), feeds)
-        for p, r in zip(planned, ref):
-            assert np.array_equal(p, r), (
-                f"{model.name}/{acc}/{mode}: executor diverges "
-                f"from golden graph reference semantics"
-            )
 
     cycles = mod.modeled_cycles()
 
@@ -87,20 +74,14 @@ def bench_cell(model, acc: str, mode: str, *, smoke: bool) -> dict:
     n_feeds = int(min(max(target_s / t_single, 3), 300))
     feeds_list = [model.feeds(seed=s) for s in range(n_feeds)]
     reps = 2 if smoke else 5
-    mod.run_many(feeds_list)  # warm both paths
-    mod.run_many(feeds_list, use_plan=False)
+    mod.run_many(feeds_list)  # warm
     t_planned = _best_of(lambda: mod.run_many(feeds_list), reps) / n_feeds
-    t_legacy = (
-        _best_of(lambda: mod.run_many(feeds_list, use_plan=False), reps) / n_feeds
-    )
     return {
         "model": model.name,
         "accelerator": acc,
         "mode": mode,
         "modeled_cycles": cycles,
         "planned_us": t_planned * 1e6,
-        "legacy_us": t_legacy * 1e6,
-        "run_many_speedup": t_legacy / t_planned,
         "n_feeds": n_feeds,
         "reps": reps,
         # per-pass rewrite/timing instrumentation from the PassManager run
@@ -125,12 +106,9 @@ def run(models: list[str], *, smoke: bool, out: Path) -> dict:
                 print(
                     f"{row['model']:>18} {row['accelerator']:>8} {row['mode']:>11} "
                     f"cycles={row['modeled_cycles']['total']:>12,.0f} "
-                    f"planned={row['planned_us']:>9.1f}us "
-                    f"legacy={row['legacy_us']:>9.1f}us "
-                    f"speedup={row['run_many_speedup']:>5.2f}x"
+                    f"planned={row['planned_us']:>9.1f}us"
                 )
 
-    best = max(rows, key=lambda r: r["run_many_speedup"])
     pass_totals: dict[str, dict[str, float]] = {}
     for r in rows:
         for p in (r.get("passes") or {}).get("passes", ()):
@@ -140,8 +118,6 @@ def run(models: list[str], *, smoke: bool, out: Path) -> dict:
             agg["rewrites"] += p["rewrites"]
             agg["duration_ms"] += p["duration_ms"]
     summary = {
-        "best_run_many_speedup": best["run_many_speedup"],
-        "best_speedup_cell": (best["model"], best["accelerator"], best["mode"]),
         "pass_totals": pass_totals,
     }
     payload = {
@@ -152,9 +128,7 @@ def run(models: list[str], *, smoke: bool, out: Path) -> dict:
         "summary": summary,
     }
     out.write_text(json.dumps(payload, indent=2))
-    print(f"\nwrote {out} ({len(rows)} cells); "
-          f"best run_many speedup {best['run_many_speedup']:.2f}x on "
-          f"{best['model']}/{best['accelerator']}/{best['mode']}")
+    print(f"\nwrote {out} ({len(rows)} cells)")
 
     # -- Table-2 claims ------------------------------------------------------
     by_cell = {(r["model"], r["accelerator"], r["mode"]): r for r in rows}
@@ -175,11 +149,6 @@ def run(models: list[str], *, smoke: bool, out: Path) -> dict:
                 f"{model}/{acc}: naive BYOC must be substantially slower "
                 f"(got {blowup:.2f}x)"
             )
-    if not smoke:
-        assert best["run_many_speedup"] >= 2.0, (
-            f"planned executor must reach >= 2x repeated-run speedup on at "
-            f"least one zoo model (best: {best['run_many_speedup']:.2f}x)"
-        )
     return payload
 
 

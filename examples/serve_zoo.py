@@ -4,9 +4,9 @@ Compiles a model-zoo network once through the ``repro.compile()`` front
 door (the zoo name routes through the traced-JAX frontend), then drives
 repeated inference with ``run_many`` — the compiled ``ExecutionPlan`` (flat
 step list, slot-indexed buffer arena, pre-padded constant weight panels) is
-built at compile time and reused across every call.  The legacy per-node
-interpreter is run on the same traffic for comparison; both paths are
-bit-exact.
+built at compile time and reused across every call.  Every answer is
+checked bit-exact against the reference semantics of the hand-built golden
+graph (``ir.execute_graph``).
 
     PYTHONPATH=src python examples/serve_zoo.py [model] [accelerator:mode]
 """
@@ -17,6 +17,7 @@ import time
 import numpy as np
 
 import repro
+from repro.core import ir
 from repro.core.zoo import get_model, model_names
 
 
@@ -28,17 +29,15 @@ def main(model_name: str = "mlp_tiny", target: str = "gemmini:optimized"):
 
     traffic = [model.feeds(seed=s) for s in range(256)]
     planned = module.run_many(traffic)
-    legacy = module.run_many(traffic, use_plan=False)
+    golden = model.build()
     assert all(
-        np.array_equal(p[0], leg[0]) for p, leg in zip(planned, legacy)
-    ), "planned executor must be bit-exact with the interpreter"
+        np.array_equal(np.asarray(p[0]), ir.execute_graph(golden, feeds)[0])
+        for p, feeds in zip(planned, traffic)
+    ), "planned executor must be bit-exact with the golden graph"
 
     t0 = time.perf_counter()
     module.run_many(traffic)
     t_planned = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    module.run_many(traffic, use_plan=False)
-    t_legacy = time.perf_counter() - t0
 
     plan = module.plan
     print(f"model={model.name} ({model.description})")
@@ -47,9 +46,7 @@ def main(model_name: str = "mlp_tiny", target: str = "gemmini:optimized"):
         f"consts, {plan.n_slots} arena slots"
     )
     print(
-        f"{len(traffic)} requests: planned {t_planned / len(traffic) * 1e6:8.1f} us/call, "
-        f"interpreter {t_legacy / len(traffic) * 1e6:8.1f} us/call "
-        f"({t_legacy / t_planned:.2f}x)"
+        f"{len(traffic)} requests: planned {t_planned / len(traffic) * 1e6:8.1f} us/call"
     )
     print(f"modeled cycles: {module.modeled_cycles()}")
 

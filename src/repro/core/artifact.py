@@ -352,7 +352,6 @@ def save_module(
         "pass_report": _report_to_dict(module.pass_report),
         "plan": _plan_skeleton(plan),
         "kernel_configs": kernel_configs,
-        "stage_assignment": list(plan.stage_assignment()),
     }
 
     def write(tmp: Path) -> None:

@@ -9,7 +9,6 @@ without a backend: ``build_plan(graph, {})`` lowers any host-only graph.
 from __future__ import annotations
 
 import math
-import queue
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable
@@ -205,10 +204,11 @@ _NONE_SLOT = 0
 class PlanStep:
     """One computed node: write ``fn(*arena[arg_slots])`` into ``slot``.
 
-    ``lane`` is the pipeline stage the step is assigned to at plan-build
-    time: ``"accel"`` for accelerator-offloaded steps, ``"host"`` for
-    everything else.  The pipelined executor runs the two lanes on two
-    threads with watermark synchronization (see ``ExecutionPlan``)."""
+    ``lane`` says where the step's work runs: ``"accel"`` for a step the
+    accelerator executes (an offloaded op or a fused device step),
+    ``"host"`` for everything else.  It names the step's span
+    (``step_span``) and nothing more: every step runs in plan order on the
+    caller's thread."""
 
     slot: int
     fn: Callable[..., np.ndarray]
@@ -217,40 +217,6 @@ class PlanStep:
     name: str
     lane: str = "host"
 
-
-class _LaneFailure(Exception):
-    """Internal: the other pipeline lane aborted; unwind quietly."""
-
-
-class _PipelineRun:
-    """Shared synchronization state of one pipelined execution stream: one
-    condition variable + abort flag covering every in-flight call, so a
-    failure in either lane (on any call) wakes every waiter."""
-
-    __slots__ = ("cond", "aborted")
-
-    def __init__(self):
-        self.cond = threading.Condition()
-        self.aborted = False
-
-    def abort(self) -> None:
-        with self.cond:
-            self.aborted = True
-            self.cond.notify_all()
-
-
-class _CallState:
-    """Per-call lane watermarks: ``done[lane]`` counts completed steps."""
-
-    __slots__ = ("run", "done")
-
-    def __init__(self, run: _PipelineRun):
-        self.run = run
-        self.done = {"host": 0, "accel": 0}
-
-
-#: sentinel pushed into the arena-handoff queue to stop the host-lane worker
-_STOP = object()
 
 #: the span of one plan execution on one feed set
 EXECUTE_SPAN = "repro.plan.execute"
@@ -267,20 +233,11 @@ class ExecutionPlan:
     """Compile-time execution plan: topological op order, input/output slot
     indices, and pre-resolved per-step callables over a flat buffer arena.
 
-    ``CompiledModule.run`` walks ``steps`` as a flat loop — no graph
+    ``CompiledModule.run`` and ``run_many`` execute a plan only through
+    ``execute``, which walks ``steps`` as a flat loop in order: no graph
     traversal, no dict-of-Node hashing, no per-call op dispatch.  Constants
     are materialized into the arena once, when it is created, and survive
-    across calls (the arena is reused by ``run_many``).
-
-    Steps additionally carry a dependency-aware *stage assignment* computed
-    here at build time: each step belongs to a lane (``host`` / ``accel``)
-    and records the cross-lane watermark it must wait for (how many steps
-    of the *other* lane must have completed before its operands exist).
-    The pipelined executor runs the host lane on a worker thread and the
-    accelerator lane on the caller's thread; within a lane steps execute in
-    topological order, so same-lane dependencies are free and cross-lane
-    dependencies reduce to one monotone counter per lane — bit-exact with
-    the sequential loop by construction (same fns, same operands)."""
+    across calls (the arena is reused by ``run_many``)."""
 
     n_slots: int
     input_slots: tuple[tuple[str, int], ...]  # (feed name, arena slot)
@@ -295,28 +252,10 @@ class ExecutionPlan:
         # the same steps, each inside its span (``repro.host.<op>`` or
         # ``repro.accel.<op>``), run instead while a profiler records: the
         # untraced loop pays one check per execution and nothing per step
-        traced_fns = [trace.spanned(step_span(s), s.fn) for s in self.steps]
         self._traced_steps = tuple(
-            (s.slot, fn, s.arg_slots) for s, fn in zip(self.steps, traced_fns)
+            (s.slot, trace.spanned(step_span(s), s.fn), s.arg_slots)
+            for s in self.steps
         )
-        # stage assignment: split steps into the two lanes, preserving topo
-        # order within each, and compute per-step cross-lane watermarks.
-        producer: dict[int, tuple[str, int]] = {}  # slot -> (lane, ordinal)
-        lanes: dict[str, list] = {"host": [], "accel": []}
-        traced_lanes: dict[str, list] = {"host": [], "accel": []}
-        for s, traced_fn in zip(self.steps, traced_fns):
-            lane = s.lane if s.lane in lanes else "host"
-            other = "accel" if lane == "host" else "host"
-            need = 0
-            for a in s.arg_slots:
-                p = producer.get(a)
-                if p is not None and p[0] == other:
-                    need = max(need, p[1] + 1)
-            producer[s.slot] = (lane, len(lanes[lane]))
-            lanes[lane].append((s.slot, s.fn, s.arg_slots, need))
-            traced_lanes[lane].append((s.slot, traced_fn, s.arg_slots, need))
-        self._lane_steps = {k: tuple(v) for k, v in lanes.items()}
-        self._traced_lane_steps = {k: tuple(v) for k, v in traced_lanes.items()}
 
     def new_arena(self) -> list:
         arena: list = [None] * self.n_slots
@@ -339,63 +278,6 @@ class ExecutionPlan:
         for slot, fn, arg_slots in steps:
             arena[slot] = fn(*[arena[i] for i in arg_slots])
         return [arena[i] for i in self.output_slots]
-
-    # -- pipelined (two-lane) execution -------------------------------------
-    def stage_assignment(self) -> tuple[dict, ...]:
-        """The build-time pipeline stage of every step: ``(name, op, lane,
-        cross-lane watermark)`` — introspection for tests, docs, and the
-        artifact manifest."""
-        out = []
-        counts = {"host": 0, "accel": 0}
-        for s in self.steps:
-            lane = s.lane if s.lane in counts else "host"
-            other = "accel" if lane == "host" else "host"
-            need = self._lane_steps[lane][counts[lane]][3]
-            counts[lane] += 1
-            out.append(
-                {"name": s.name, "op": s.op, "lane": lane, f"waits_{other}": need}
-            )
-        return tuple(out)
-
-    def lane_sizes(self) -> dict[str, int]:
-        return {k: len(v) for k, v in self._lane_steps.items()}
-
-    def recorded_lane_steps(self) -> dict[str, tuple]:
-        """The precomputed per-lane ``(slot, fn, arg_slots, watermark)``
-        tuples the pipelined executor actually runs — exposed so
-        ``repro.core.verify`` can independently re-derive the watermarks
-        and check dominance (the static race detector)."""
-        return self._lane_steps
-
-    def execute_lane(self, arena: list, state: _CallState, lane: str) -> None:
-        """Run one lane of one call.  Steps run in topo order; before each
-        step the other lane's watermark must reach the step's recorded
-        dependency count.  Raises ``_LaneFailure`` if the run aborts."""
-        other = "accel" if lane == "host" else "host"
-        run = state.run
-        cond, done = run.cond, state.done
-        steps = self._traced_lane_steps if trace.enabled() else self._lane_steps
-        for slot, fn, arg_slots, need in steps[lane]:
-            if need and done[other] < need:
-                with cond:
-                    while done[other] < need and not run.aborted:
-                        cond.wait()
-                    if run.aborted:
-                        raise _LaneFailure()
-            arena[slot] = fn(*[arena[i] for i in arg_slots])
-            with cond:
-                done[lane] += 1
-                cond.notify_all()
-
-    def wait_lane(self, state: _CallState, lane: str) -> None:
-        """Block until ``lane`` has completed every step of this call."""
-        n = len(self._lane_steps[lane])
-        run = state.run
-        with run.cond:
-            while state.done[lane] < n and not run.aborted:
-                run.cond.wait()
-            if run.aborted:
-                raise _LaneFailure()
 
 
 def _attn_epilogues(
@@ -669,27 +551,11 @@ class CompiledModule:
             if len(self._arena_pool) < 16:
                 self._arena_pool.append(arena)
 
-    def run(
-        self,
-        feeds: dict[str, np.ndarray],
-        *,
-        use_plan: bool = True,
-        pipelined: bool = False,
-    ) -> list[np.ndarray]:
-        """Execute the module.  Thread-safe: every call runs over its own
-        buffer arena (pooled, so steady-state traffic allocates nothing).
-        ``use_plan=False`` runs the legacy per-node interpreter (kept for
-        planned-vs-interpreted equivalence testing and as the baseline of
-        ``benchmarks/table2_bench.py``).  ``pipelined=True`` overlaps the
-        host-op lane with accelerator-step dispatch on a worker thread —
-        bit-exact with the sequential loop (same fns, same operand order)."""
+    def run(self, feeds: dict[str, np.ndarray]) -> list[np.ndarray]:
+        """Execute the module's plan on one feed set.  Thread-safe: every
+        call runs over its own buffer arena (pooled, so steady-state
+        traffic allocates nothing)."""
         self._check_feeds(feeds)
-        if pipelined:
-            if not use_plan:
-                raise ValueError("pipelined execution requires use_plan=True")
-            return self._run_many_pipelined([feeds], self.finalize())[0]
-        if not use_plan:
-            return self._run_interpreted(feeds)
         plan = self.finalize()
         arena = self._acquire_arena(plan)
         try:
@@ -698,29 +564,14 @@ class CompiledModule:
             self._release_arena(arena)
 
     def run_many(
-        self,
-        feeds_list: list[dict[str, np.ndarray]],
-        *,
-        use_plan: bool = True,
-        pipelined: bool = False,
+        self, feeds_list: list[dict[str, np.ndarray]]
     ) -> list[list[np.ndarray]]:
         """Repeated invocation over a list of feeds (serving-style traffic);
         the plan is built once and one pooled arena is held for the whole
         loop.  Thread-safe: concurrent callers each hold their own arena,
-        so compiled modules can be shared across serving threads.
-
-        ``pipelined=True`` runs the host lane on a worker thread and rotates
-        two arenas through a free/ready queue pair (double buffering): while
-        the caller dispatches call *i*'s accelerator steps, the worker is
-        already loading feeds and running host stages of call *i+1*."""
+        so compiled modules can be shared across serving threads."""
         for feeds in feeds_list:
             self._check_feeds(feeds)
-        if pipelined:
-            if not use_plan:
-                raise ValueError("pipelined execution requires use_plan=True")
-            return self._run_many_pipelined(feeds_list, self.finalize())
-        if not use_plan:
-            return [self._run_interpreted(f) for f in feeds_list]
         plan = self.finalize()
         arena = self._acquire_arena(plan)
         try:
@@ -728,102 +579,6 @@ class CompiledModule:
             return [execute(feeds, arena) for feeds in feeds_list]
         finally:
             self._release_arena(arena)
-
-    def _run_many_pipelined(
-        self, feeds_list: list[dict[str, np.ndarray]], plan: "ExecutionPlan"
-    ) -> list[list[np.ndarray]]:
-        """Two-lane, double-buffered execution.  A worker thread owns the
-        host lane; the caller's thread owns the accelerator lane.  Two
-        arenas rotate through ``free``/``ready`` queues so consecutive calls
-        overlap (depth-2 pipeline); cross-lane dependencies inside one call
-        are enforced by the plan's build-time watermarks.  Any exception on
-        either side aborts the shared run, unblocks every waiter, and
-        re-raises in the caller."""
-        if not feeds_list:
-            return []
-        sizes = plan.lane_sizes()
-        if not sizes["accel"] or not sizes["host"]:
-            # one lane is empty: nothing to overlap, the sequential loop is
-            # strictly better (and spawns no thread).
-            arena = self._acquire_arena(plan)
-            try:
-                return [plan.execute(f, arena) for f in feeds_list]
-            finally:
-                self._release_arena(arena)
-        run = _PipelineRun()
-        free: queue.SimpleQueue = queue.SimpleQueue()
-        ready: queue.SimpleQueue = queue.SimpleQueue()
-        arenas = [self._acquire_arena(plan), self._acquire_arena(plan)]
-        for a in arenas:
-            free.put(a)
-        worker_exc: list[BaseException] = []
-
-        def host_worker() -> None:
-            try:
-                for feeds in feeds_list:
-                    arena = free.get()
-                    if arena is _STOP:
-                        return
-                    for name, slot in plan.input_slots:
-                        arena[slot] = np.asarray(feeds[name])
-                    state = _CallState(run)
-                    # publish before executing: the accel lane starts as
-                    # soon as the feeds are in place.
-                    ready.put((arena, state))
-                    plan.execute_lane(arena, state, "host")
-            except _LaneFailure:
-                pass  # the caller aborted; it owns the original exception
-            except BaseException as e:  # noqa: BLE001 — re-raised in caller
-                worker_exc.append(e)
-                run.abort()
-                ready.put(_STOP)
-
-        t = threading.Thread(
-            target=host_worker, name="repro-host-lane", daemon=True
-        )
-        t.start()
-        results: list[list[np.ndarray]] = []
-        try:
-            try:
-                for _ in feeds_list:
-                    item = ready.get()
-                    if item is _STOP:
-                        break  # worker died; its exception re-raised below
-                    arena, state = item
-                    with trace.span(EXECUTE_SPAN):
-                        plan.execute_lane(arena, state, "accel")
-                        plan.wait_lane(state, "host")
-                    results.append([arena[i] for i in plan.output_slots])
-                    free.put(arena)
-            except _LaneFailure:
-                pass  # abort came from the worker; re-raised below
-            except BaseException:
-                run.abort()
-                raise
-            finally:
-                free.put(_STOP)  # unblock a worker parked on free.get()
-                t.join()
-        finally:
-            for a in arenas:
-                self._release_arena(a)
-        if worker_exc:
-            raise worker_exc[0]
-        return results
-
-    def _run_interpreted(self, feeds: dict[str, np.ndarray]) -> list[np.ndarray]:
-        """The pre-plan per-node interpreter: re-toposorts and re-dispatches
-        on every call."""
-        vals: dict[Node, np.ndarray] = {}
-        for n in self.graph.toposort():
-            if n.op == "input":
-                vals[n] = np.asarray(feeds[n.name])
-            else:
-                ins = [vals[i] if i is not None else None for i in n.inputs]
-                if n in self.ops:
-                    vals[n] = self.ops[n].executor(*ins)
-                else:
-                    vals[n] = execute_node(n, ins)
-        return [vals[o] for o in self.graph.outputs]
 
     # -- cycle model ---------------------------------------------------------
     def modeled_cycles(self) -> dict[str, float]:

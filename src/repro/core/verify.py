@@ -1,5 +1,5 @@
 """repro.verify — the static verification layer (IR type-checker,
-pass-invariant gate, plan lifetime/race analysis, collective deadlock
+pass-invariant gate, plan lifetime analysis, collective deadlock
 detection).
 
 Every ``ir.Node`` carries its (shape, dtype) fixed at construction, so a
@@ -16,9 +16,7 @@ This module re-derives everything a graph/plan claims about itself from an
     ``CacheSpec`` state-wiring consistency;
   * :func:`verify_plan`    — arena-slot def/use simulation over
     ``ExecutionPlan`` steps (read-before-write, clobbered slots, slot
-    bounds, undefined outputs) plus an independent re-derivation of the
-    pipelined executor's cross-lane watermarks — a static race detector
-    for the two-lane path;
+    bounds, undefined outputs);
   * :func:`verify_collectives` — cross-shard consistency of the collective
     sequences a sharded plan set issues: every group's membership must be
     complete and identical in (op, parts, axis, dtype, contribution
@@ -49,7 +47,6 @@ Diagnostic codes:
   ``P_UNWRITTEN`` plan step reads a slot no earlier step defines
   ``P_CLOBBER``   plan step overwrites a live (already defined) slot
   ``P_OUTPUT``    plan output slot never defined
-  ``P_RACE``      recorded cross-lane watermark below the required one
   ``C_MISMATCH``  collective group membership/shape/op mismatch
   ``C_ORDER``     two shards order their common collectives differently
   ``S_SCHEDULE``  selected schedule violates a hardware constraint
@@ -814,33 +811,12 @@ def verify_graph(graph: ir.Graph, desc=None) -> list[Diagnostic]:
 
 
 # ---------------------------------------------------------------------------
-# plan analysis: arena def/use + the cross-lane watermark race detector
+# plan analysis: arena def/use
 # ---------------------------------------------------------------------------
 
 
-def _expected_lane_steps(plan: ExecutionPlan) -> dict[str, list]:
-    """Independently re-derive the two-lane stage assignment and cross-lane
-    watermarks from the plan's step list (the same dominance rule
-    ``ExecutionPlan.__post_init__`` encodes: a step must wait until the
-    other lane has completed every step producing one of its operands)."""
-    producer: dict[int, tuple[str, int]] = {}
-    lanes: dict[str, list] = {"host": [], "accel": []}
-    for s in plan.steps:
-        lane = s.lane if s.lane in lanes else "host"
-        other = "accel" if lane == "host" else "host"
-        need = 0
-        for a in s.arg_slots:
-            p = producer.get(a)
-            if p is not None and p[0] == other:
-                need = max(need, p[1] + 1)
-        producer[s.slot] = (lane, len(lanes[lane]))
-        lanes[lane].append((s.slot, tuple(s.arg_slots), need))
-    return lanes
-
-
 def verify_plan(plan: ExecutionPlan) -> list[Diagnostic]:
-    """Simulate arena-slot def/use over the plan's steps and re-check the
-    pipelined executor's precomputed cross-lane watermarks."""
+    """Simulate arena-slot def/use over the plan's steps."""
     diags: list[Diagnostic] = []
     n = plan.n_slots
 
@@ -891,37 +867,6 @@ def verify_plan(plan: ExecutionPlan) -> list[Diagnostic]:
     for i, slot in enumerate(plan.output_slots):
         if not (0 <= slot < n) or slot not in defined:
             diag("P_OUTPUT", f"output {i}", f"slot {slot} is never defined")
-    # -- cross-lane watermark dominance (the two-lane race detector) ---------
-    expected = _expected_lane_steps(plan)
-    recorded = plan.recorded_lane_steps()
-    for lane in ("host", "accel"):
-        exp, rec = expected[lane], recorded.get(lane, ())
-        if len(exp) != len(rec):
-            diag(
-                "P_RACE",
-                f"lane {lane!r}",
-                f"recorded lane has {len(rec)} steps, step list implies "
-                f"{len(exp)} — lanes desynchronized",
-            )
-            continue
-        for k, ((slot, args, need), r) in enumerate(zip(exp, rec)):
-            r_slot, _fn, r_args, r_need = r
-            if r_slot != slot or tuple(r_args) != args:
-                diag(
-                    "P_RACE",
-                    f"lane {lane!r} step {k}",
-                    f"recorded step writes slot {r_slot} from {list(r_args)}, "
-                    f"step list implies slot {slot} from {list(args)}",
-                )
-            elif r_need < need:
-                diag(
-                    "P_RACE",
-                    f"lane {lane!r} step {k} (slot {slot})",
-                    f"recorded cross-lane watermark {r_need} does not "
-                    f"dominate the required {need}: the "
-                    f"{'accel' if lane == 'host' else 'host'} lane may not "
-                    f"have produced an operand when this step runs",
-                )
     return diags
 
 

@@ -203,12 +203,12 @@ def test_feed_validation_lists_all_problems(module):
     assert "x: int8[1, 16]" in msg  # the expected signature
 
 
-def test_feed_validation_applies_to_run_many_and_legacy_path(module):
+def test_feed_validation_applies_to_run_many(module):
     good = get_model("mlp_tiny").feeds(seed=0)
     with pytest.raises(repro.FeedError, match="unknown feed 'extra'"):
         module.run_many([good, {**good, "extra": 1}])
     with pytest.raises(repro.FeedError, match="missing feed"):
-        module.run({}, use_plan=False)
+        module.run_many([{}])
 
 
 def test_feed_error_is_a_key_error(module):

@@ -1,7 +1,8 @@
 """Planned graph executor: plan structure, bit-exact equivalence with the
-legacy per-node interpreter across the model zoo x modes x accelerators
-(including the Pallas interpret-mode TPU path), run_many semantics, and the
-quantized-flag / free-view cycle-model fixes in the pipeline."""
+golden graph's reference semantics across the model zoo x modes x
+accelerators (including the Pallas interpret-mode TPU path), run_many
+semantics, and the quantized-flag / free-view cycle-model fixes in the
+pipeline."""
 
 import numpy as np
 import pytest
@@ -22,7 +23,6 @@ MAKERS = {
     "tpu_v5e": make_tpu_v5e_description,
 }
 MODES = ("proposed", "c_toolchain", "naive")
-NUMPY_EXACT = {"gemmini", "edge_npu"}
 
 _BACKENDS: dict[str, object] = {}
 
@@ -33,7 +33,14 @@ def _backend(acc: str):
     return _BACKENDS[acc]
 
 
-# -- planned vs legacy equivalence across the zoo -----------------------------
+# -- the plan against the golden graph across the zoo -------------------------
+
+
+def _assert_matches_reference(planned, ref):
+    assert len(planned) == len(ref)
+    for p, r in zip(planned, ref):
+        p = np.asarray(p)
+        assert p.dtype == r.dtype and np.array_equal(p, r)
 
 
 @pytest.mark.parametrize("mode", MODES)
@@ -41,32 +48,22 @@ def _backend(acc: str):
     "model_name,acc",
     [(m.name, a) for m in ZOO.values() for a in m.accelerators if a != "tpu_v5e"],
 )
-def test_planned_matches_legacy_zoo(model_name, acc, mode):
+def test_plan_matches_reference_zoo(model_name, acc, mode):
     model = get_model(model_name)
     mod = _backend(acc).compile_graph(model.build(), mode=mode)
     feeds = model.feeds(seed=3)
-    planned = mod.run(feeds)
-    legacy = mod.run(feeds, use_plan=False)
-    for p, leg in zip(planned, legacy):
-        assert p.dtype == leg.dtype and np.array_equal(p, leg)
-    if acc in NUMPY_EXACT:
-        ref = ir.execute_graph(model.build(), feeds)
-        for p, r in zip(planned, ref):
-            assert np.array_equal(p, r)
+    _assert_matches_reference(mod.run(feeds), ir.execute_graph(model.build(), feeds))
 
 
 @pytest.mark.parametrize("mode", MODES)
-def test_planned_matches_legacy_tpu_pallas_interpret(mode):
-    """The Pallas interpret-mode TPU path must agree between the planned
-    executor and the per-node interpreter in all three modes."""
+def test_plan_matches_reference_tpu_pallas_interpret(mode):
+    """The Pallas interpret-mode TPU path is bit-exact with the golden
+    graph in all three modes."""
     backend = build_backend(make_tpu_v5e_description(), use_pallas=True)
     model = get_model("mlp_tiny")
     mod = backend.compile_graph(model.build(), mode=mode)
     feeds = model.feeds(seed=5)
-    planned = mod.run(feeds)
-    legacy = mod.run(feeds, use_plan=False)
-    for p, leg in zip(planned, legacy):
-        assert np.array_equal(np.asarray(p), np.asarray(leg))
+    _assert_matches_reference(mod.run(feeds), ir.execute_graph(model.build(), feeds))
 
 
 # -- plan structure ------------------------------------------------------------
@@ -108,9 +105,8 @@ def test_run_many_reuses_arena_and_results_stay_independent():
     mod.run_many([{"x": np.full((1, 16), 9, dtype=np.int8)}] * 4)
     for out, snap in zip(outs, snapshots):
         assert np.array_equal(out[0], snap)
-    legacy = mod.run_many(feeds, use_plan=False)
-    for p, leg in zip(outs, legacy):
-        assert np.array_equal(p[0], leg[0])
+    for p, f in zip(outs, feeds):
+        assert np.array_equal(p[0], ir.execute_graph(mlp_graph((16,) * 3), f)[0])
 
 
 def test_run_missing_feed_raises_keyerror():
@@ -133,8 +129,6 @@ def test_plan_handles_none_inputs():
     feeds = {"x": np.ones((4, 8), dtype=np.int8)}
     expected = np.full((4, 8), 8, dtype=np.int32)
     assert np.array_equal(mod.run(feeds)[0], expected)
-    # the legacy interpreter must accept optional None operands too
-    assert np.array_equal(mod.run(feeds, use_plan=False)[0], expected)
 
 
 def test_inplace_accumulating_intrinsic_stays_correct():
@@ -160,7 +154,6 @@ def test_inplace_accumulating_intrinsic_stays_correct():
     r1 = mod.run(feeds)[0].copy()
     for _ in range(3):  # identical feeds must keep producing identical outputs
         assert np.array_equal(mod.run(feeds)[0], r1)
-    assert np.array_equal(mod.run(feeds, use_plan=False)[0], r1)
 
 
 def test_softmax_charged_as_host_epilogue():
@@ -206,7 +199,6 @@ def test_quantized_flag_from_node_attrs(acc):
     graph, feeds, expected = _manual_generalized(epi)
     mod = _backend(acc).compile_graph(graph, mode="proposed")
     assert np.array_equal(mod.run(feeds)[0], expected)
-    assert np.array_equal(mod.run(feeds, use_plan=False)[0], expected)
 
 
 @pytest.mark.parametrize("acc", ["gemmini", "edge_npu"])
@@ -217,7 +209,6 @@ def test_quantized_flag_from_strategy_compute(acc):
     graph, feeds, expected = _manual_generalized(epi)
     mod = _backend(acc).compile_graph(graph, mode="proposed")
     assert np.array_equal(mod.run(feeds)[0], expected)
-    assert np.array_equal(mod.run(feeds, use_plan=False)[0], expected)
 
 
 def test_quantized_missing_epilogue_attrs_is_compile_error():
@@ -268,7 +259,6 @@ def test_flatten_node_executes_like_reshape():
     mod = _backend("gemmini").compile_graph(Graph([n]), mode="proposed")
     expected = feeds["x"].reshape(2, 32)
     assert np.array_equal(mod.run(feeds)[0], expected)
-    assert np.array_equal(mod.run(feeds, use_plan=False)[0], expected)
 
 
 # -- build_plan is usable standalone ------------------------------------------

@@ -342,7 +342,7 @@ def test_fixed_point_guard():
 def test_multi_output_graph_compiles_and_runs():
     """Both outputs of a multi-output graph survive compilation in every
     mode, with the first output feeding the second chain AND being
-    observable — planned, legacy, and reference all agree."""
+    observable — planned and reference agree."""
     def build():
         x = ir.input_((2, 16), "int8", name="x")
         h1 = _qchain(x)
@@ -355,10 +355,9 @@ def test_multi_output_graph_compiles_and_runs():
     for mode in ("proposed", "c_toolchain", "naive"):
         mod = backend.compile_graph(build(), mode=mode)
         planned = mod.run(feeds)
-        legacy = mod.run(feeds, use_plan=False)
         assert len(planned) == 2
-        for p, leg, r in zip(planned, legacy, ref):
-            assert np.array_equal(p, leg) and np.array_equal(p, r), mode
+        for p, r in zip(planned, ref):
+            assert np.array_equal(p, r), mode
     # in optimized modes both chains legalized even though h1 is an output
     mod_opt = backend.compile_graph(build(), mode="proposed")
     gens = [n for n in mod_opt.graph.toposort() if n.op == "generalized_dense"]

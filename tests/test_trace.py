@@ -155,7 +155,7 @@ def test_no_span_is_made_without_a_profiler(tpu_module, monkeypatch, tmp_path):
     _CountingAnnotation.made = 0
     assert not trace.enabled()
     _serve(tpu_module, 5)
-    tpu_module.bucket_module(4).run({"x": np.zeros((4, WIDTHS[0]), np.int8)}, pipelined=True)
+    tpu_module.bucket_module(4).run({"x": np.zeros((4, WIDTHS[0]), np.int8)})
     assert _CountingAnnotation.made == 0
     # the same calls under a profiler do make spans
     with jax.profiler.trace(str(tmp_path)):
@@ -193,15 +193,15 @@ def test_cpu_capture_of_run_many_counts_every_step(tpu_module, tmp_path):
     assert p.idle_by_program_span == []  # no chip in a CPU trace
 
 
-def test_cpu_capture_of_the_two_lane_executor_names_host_steps(tmp_path):
-    """The two-lane executor runs host steps on its worker thread; each
-    still gets its ``repro.host.<op>`` span, and each call one execution."""
+def test_cpu_capture_of_run_many_names_host_steps(tmp_path):
+    """Each host step of a plan gets its ``repro.host.<op>`` span, and
+    each call one execution."""
     model = zoo.get_model("transformer_block")
     module = repro.compile("transformer_block", repro.Target("gemmini", cache=False))
     feeds = [model.feeds(seed=s) for s in range(3)]
     host_steps = [s.op for s in module.plan.steps if s.lane == "host"]
     assert host_steps and len(host_steps) < len(module.plan.steps)
-    p = _capture(tmp_path, lambda: module.run_many(feeds, pipelined=True))
+    p = _capture(tmp_path, lambda: module.run_many(feeds))
     spans = p.program_spans
     assert spans["repro.plan.execute"][0] == 3
     for op in set(host_steps):

@@ -254,26 +254,12 @@ def test_slot_outside_arena_is_P_BOUNDS():
     assert "P_BOUNDS" in codes(verify_plan(plan))
 
 
-def test_compiled_plans_are_clean_and_injected_watermark_race_is_P_RACE():
-    # naive mode interleaves host epilogues with accel GEMMs, so the
-    # two-lane split has real cross-lane watermarks to tamper with
+def test_compiled_plans_are_clean():
+    # naive mode interleaves host epilogues with accel GEMMs
     module = repro.compile("mlp_tiny", target=repro.Target("gemmini", mode="naive"))
     plan = module.finalize()
+    assert {s.lane for s in plan.steps} == {"host", "accel"}
     assert verify_plan(plan) == []
-    recorded = {k: list(v) for k, v in plan.recorded_lane_steps().items()}
-    lane, idx = next(
-        (lane, i)
-        for lane, steps in recorded.items()
-        for i, s in enumerate(steps)
-        if s[3] > 0
-    )
-    slot, fn, args, need = recorded[lane][idx]
-    # the stale watermark: this step may now run before the other lane has
-    # produced one of its operands
-    recorded[lane][idx] = (slot, fn, args, need - 1)
-    plan._lane_steps = {k: tuple(v) for k, v in recorded.items()}
-    diags = verify_plan(plan)
-    assert "P_RACE" in codes(diags)
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,7 @@ from repro.core.ir import (
     moe_dispatch_ref,
     moe_group_sizes_ref,
     moe_route_ref,
+    requantize_ref,
     rms_norm_ref,
     rope_ref,
     silu_mul_ref,
@@ -92,10 +93,19 @@ def compile_host_op(n: Node) -> Callable[..., np.ndarray]:
         if dtype.startswith(("int", "uint")):
             info = np.iinfo(dtype)
             lo, hi = info.min, info.max
-            return lambda x: np.clip(
-                np.round(x.astype(np.float64) * scale), lo, hi
-            ).astype(dtype)
-        return lambda x: np.round(x.astype(np.float64) * scale).astype(dtype)
+            requant = lambda x: requantize_ref(x, scale, lo, hi, dtype)
+        else:
+            requant = lambda x: np.round(x.astype(np.float64) * scale).astype(dtype)
+        src = n.inputs[0]
+        if src.op.replace("generalized_", "") != "dense" or len(src.inputs[1].shape) != 3:
+            return requant
+        rows = math.prod(n.shape[:-1])
+
+        def _requantize_bmm(x):
+            trace.count_bmm_requant_rows(0, rows)
+            return requant(x)
+
+        return _requantize_bmm
     if op == "quantize":
         scale = attrs["scale"]
         return lambda x: np.clip(np.round(x / scale), -128, 127).astype(dtype)

@@ -611,6 +611,18 @@ def _row_blocks(n_rows: int, row_elems: int):
     return (slice(i, i + step) for i in range(0, n_rows, step))
 
 
+def requantize_ref(acc: np.ndarray, scale: float, lo: float, hi: float,
+                   dtype) -> np.ndarray:
+    """``clip(rint(acc · scale), lo, hi)`` in float64, to ``dtype``, a block
+    of rows at a time: the bits of the ``requantize`` op (``lo``, ``hi`` its
+    dtype's range)."""
+    a2 = acc.reshape(-1, acc.shape[-1])
+    out = np.empty(a2.shape, dtype)
+    for rows in _row_blocks(*a2.shape):
+        out[rows] = np.clip(np.rint(a2[rows].astype(np.float64) * scale), lo, hi)
+    return out.reshape(acc.shape)
+
+
 def rms_norm_ref(x: np.ndarray, in_scale: float, out_scale: float, eps: float) -> np.ndarray:
     """``y = x·s / sqrt(mean((x·s)²) + eps)`` over the last axis, to int8 at
     ``out_scale``.  The sum of squares is exact in int64; then, in float64:

@@ -45,7 +45,14 @@ import numpy as np
 from repro.core import trace
 from repro.core.accel import AcceleratorDescription
 from repro.core.intrinsics import HardwareIntrinsicGenerator
-from repro.core.ir import Node, execute_node, gelu_ref, max_pool2d_ref, silu_mul_ref
+from repro.core.ir import (
+    Node,
+    execute_node,
+    gelu_ref,
+    max_pool2d_ref,
+    requantize_ref,
+    silu_mul_ref,
+)
 from repro.core.mapping import MappingGenerator
 from repro.core.scheduler import grouped_block_m
 from repro.core.strategy import Strategy
@@ -460,7 +467,12 @@ def _make_pallas_executor(
         path registers);
       * batched activation-activation matmuls (PR-5 3-D dense) replay the
         per-sample scheduled kernel per batch instance — one jit compile,
-        since instances share shape and config;
+        since instances share shape and config.  A quantized one (the
+        attention context) requantizes in the kernel's epilogue where
+        ``_bmm_requant_exact`` holds, and syncs int8; elsewhere it runs
+        the int32 kernel and requantizes on the host after the sync, in
+        row blocks (``ir.requantize_ref``).  Counts
+        ``bmm_requant_device_rows`` / ``bmm_requant_host_rows``;
       * the ``pool`` epilogue reduces the epilogued conv output on the
         host, and ``residual`` is added last, exactly like the emulated
         executor.
@@ -485,9 +497,21 @@ def _make_pallas_executor(
     # requantize/clip only fires on resolved-quantized generalized ops;
     # everything else gets at most an activation.
     cfg = kernel_config_for(desc, mapping_gen, node, strategy)
+    # a quantized batched dense whose requantize the kernel cannot do with
+    # the host's bits (``_bmm_requant_exact``) runs the int32 kernel, and
+    # the host requantizes its result after the sync
+    host_requant = is_bmm and fused_quant and not _bmm_requant_exact(node)
+    if host_requant:
+        cfg = dataclasses.replace(
+            cfg, requant_scale=None, clip_lo=None, clip_hi=None, out_dtype="int32"
+        )
+        scale, clip_lo, clip_hi = (
+            attrs["requant_scale"], attrs["clip_lo"], attrs["clip_hi"]
+        )
+    bmm_rows = math.prod(out_shape[:-1]) if is_bmm and fused_quant else 0
 
     def _run2d(x_j, w_j, b_j):
-        if fused_quant:
+        if fused_quant and not host_requant:
             return kops.qmatmul(x_j, w_j, b_j, cfg)
         return kops.matmul(x_j, w_j, cfg, b_j)
 
@@ -534,7 +558,13 @@ def _make_pallas_executor(
     def pallas_exec(x, w, bias=None, residual=None):
         on = trace.enabled()
         b_j = _to_device(bias, on) if bias is not None else None
-        out = _finish(_to_host(_device_out(on, x, w, b_j), on))
+        out = _to_host(_device_out(on, x, w, b_j), on)
+        if host_requant:
+            out = requantize_ref(out, scale, clip_lo, clip_hi, out_dtype)
+            trace.count_bmm_requant_rows(0, bmm_rows)
+        elif bmm_rows:
+            trace.count_bmm_requant_rows(bmm_rows, 0)
+        out = _finish(out)
         if residual is not None:
             out = out + residual
         return out
@@ -844,6 +874,28 @@ def _attn_scores_exact(node: Node) -> bool:
 
 def _power_of_two(scale: float) -> bool:
     return scale > 0 and math.frexp(scale)[0] == 0.5
+
+
+#: the longest contraction of int8 operands whose int32 accumulator is exact
+#: in float32: ``|acc| <= K * 128 * 128 = K * 2^14 <= 2^24``
+BMM_REQUANT_MAX_K = 1024
+
+
+def _bmm_requant_exact(node: Node) -> bool:
+    """Whether the quantized GEMM kernel's epilogue, ``clip(round(float32(acc)
+    * scale))``, gives the host's float64 ``clip(rint(acc * scale))`` bit for
+    bit on every instance of the batched dense ``node``: int8 operands with
+    K <= ``BMM_REQUANT_MAX_K`` and no bias, so ``float32(acc)`` is exact; a
+    power-of-two scale, so the product is exact too (or, far out of range,
+    saturates alike); both then round the same value half to even.  An int8
+    result, as the kernel writes.  Decided from the node, once."""
+    return (
+        all(i.dtype == "int8" for i in node.inputs[:2])
+        and (len(node.inputs) < 3 or node.inputs[2] is None)
+        and node.inputs[0].shape[-1] <= BMM_REQUANT_MAX_K
+        and node.dtype == "int8"
+        and _power_of_two(node.attrs["requant_scale"])
+    )
 
 
 def _attn_q(s, mask, scale: float, probs_scale: float):

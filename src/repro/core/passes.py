@@ -180,12 +180,42 @@ def _fuse_grouped(m: Match, graph: Graph) -> Node | None:
     )
 
 
+def _batched_int8(n: Node) -> bool:
+    """A batched activation-activation dense (3-D second operand, as the
+    attention context) of two int8 operands into int32."""
+    return (
+        len(n.inputs[1].shape) == 3
+        and n.dtype == "int32"
+        and all(i.dtype == "int8" for i in n.inputs)
+    )
+
+
+@rule(
+    "fuse-batched-requantize",
+    P("requantize", P("dense", capture="core", where=_batched_int8), capture="rq",
+      where=lambda n: n.dtype.startswith("int")),
+)
+def _fuse_batched(m: Match, graph: Graph) -> Node | None:
+    """requantize(batched int8 dense) to an int dtype -> one quantized
+    generalized dense with an absent bias (the requantize saturates to its
+    dtype), so the requantize runs in the kernel's epilogue."""
+    core, root = m["core"], m.root
+    return Node(
+        "generalized_dense",
+        [core.inputs[0], core.inputs[1], None],
+        _quantized_attrs(core, m["rq"], None),
+        shape=root.shape,
+        dtype=root.dtype,
+    )
+
+
 LEGALIZE_RULES = (
     _fuse_quantized,
     _fuse_activation,
     _fuse_bias,
     _fuse_quantized_nobias,
     _fuse_grouped,
+    _fuse_batched,
 )
 
 

@@ -34,6 +34,11 @@ Span names (docs/architecture.md, "Observability"):
   GEMM and the SiLU·mul after it on the device, exact against the host's
   table (``lowering._swiglu_step``).
 
+A batched int8 dense (the attention context) whose sole consumer was an
+integer ``requantize`` runs as a quantized ``generalized_dense`` step,
+``repro.accel.generalized_dense``, its requantize in the kernel's epilogue
+(``lowering._make_pallas_executor``).
+
 Counters: ``h2d_transfers`` / ``h2d_bytes``, ``d2h_syncs`` / ``d2h_bytes``,
 ``kernel_launches``; ``attn_epilogue_rows`` — rows of scores the device
 epilogue ran over; ``attn_fallback_rows`` — those of them the host chain
@@ -44,7 +49,12 @@ routed ones, from row-tile padding at expert boundaries;
 busiest expert's rows (``expert_rows_peak / expert_rows`` is the routing's
 imbalance: 1 when every expert gets as many rows); ``swiglu_device_rows``
 — rows of SiLU·mul the fused ``swiglu`` step computed on the device;
-``swiglu_host_rows`` — rows the host ``silu_mul`` step computed.
+``swiglu_host_rows`` — rows the host ``silu_mul`` step computed;
+``bmm_requant_device_rows`` — rows of a batched int8 dense whose
+requantize the kernel's epilogue did on the device;
+``bmm_requant_host_rows`` — rows of a batched dense requantized on the
+host: after a quantized batched step whose epilogue the device cannot do
+exactly, or by a host ``requantize`` step after a batched dense.
 """
 
 from __future__ import annotations
@@ -68,6 +78,8 @@ COUNTERS = (
     "expert_rows_peak",
     "swiglu_device_rows",
     "swiglu_host_rows",
+    "bmm_requant_device_rows",
+    "bmm_requant_host_rows",
 )
 
 _NULL = contextlib.nullcontext()
@@ -133,6 +145,12 @@ def count_swiglu_rows(device: int, host: int) -> None:
     with _lock:
         _counts["swiglu_device_rows"] += device
         _counts["swiglu_host_rows"] += host
+
+
+def count_bmm_requant_rows(device: int, host: int) -> None:
+    with _lock:
+        _counts["bmm_requant_device_rows"] += device
+        _counts["bmm_requant_host_rows"] += host
 
 
 def snapshot() -> dict[str, int]:

@@ -136,6 +136,10 @@ def test_musicgen_fused_plan_matches_unfused_and_reference(seed):
     np.testing.assert_array_equal(got[0], cell.ref.reference(cfg, params, x))
     rows = 2 * cfg["num_attention_heads"] * 16
     assert moved["attn_epilogue_rows"] == cfg["num_hidden_layers"] * rows
+    # the context's requantize runs in its kernel's epilogue, none on the host
+    assert "requantize" not in _ops(module.plan)
+    assert moved["bmm_requant_device_rows"] == cfg["num_hidden_layers"] * rows
+    assert moved["bmm_requant_host_rows"] == 0
 
 
 def test_musicgen_plan_has_four_fewer_steps_per_layer():
@@ -144,7 +148,11 @@ def test_musicgen_plan_has_four_fewer_steps_per_layer():
     assert len(plain) - len(fused) == 4 * cfg["num_hidden_layers"]
     for op in ("dequantize", "add", "softmax", "quantize"):
         assert plain.count(op) == cfg["num_hidden_layers"] and op not in fused
-    assert plain.count("dense") == fused.count("dense") + fused.count("attn_scores")
+    # the scores GEMM is a plain int32 dense; the context a quantized
+    # generalized dense (q, k, v, o and the FFN's two besides)
+    assert plain.count("dense") == fused.count("attn_scores") == cfg["num_hidden_layers"]
+    assert "dense" not in fused and "requantize" not in plain
+    assert fused.count("generalized_dense") == 7 * cfg["num_hidden_layers"]
 
 
 @pytest.mark.parametrize("batch", [None, 3])

@@ -346,6 +346,11 @@ def test_mixtral_stack_matches_the_reference_exactly(mixtral):
     # SiLU-mul on the device over each layer's routed rows, none on the host
     assert counted["swiglu_device_rows"] == 2 * 64
     assert counted["swiglu_host_rows"] == 0
+    # the context's requantize in its kernel's epilogue: per layer 2 x 2
+    # instances of 2 x 16 stacked query rows, none on the host
+    assert counted["bmm_requant_device_rows"] == 2 * 4 * 32
+    assert counted["bmm_requant_host_rows"] == 0
+    assert "requantize" not in [s.op for s in module.modules[2].plan.steps]
 
 
 def test_expert_weights_stay_on_the_device_after_the_first_call(mixtral):
@@ -370,8 +375,11 @@ def test_mixtral_plan_has_one_grouped_step_per_expert_projection(mixtral):
     assert ops.count("swiglu") == 2
     assert ops.count("generalized_grouped_dense") == 2
     assert "silu_mul" not in ops
-    # q, k, v and o take the quantized chain without a bias
-    assert ops.count("generalized_dense") == 2 * 4
+    # q, k, v and o take the quantized chain without a bias, and the
+    # context its requantize in the kernel's epilogue
+    assert ops.count("generalized_dense") == 2 * 5
+    assert "requantize" not in ops
+    assert ops.count("dense") == 2  # the router's int32 logits
     assert ops.count("attn_scores") == 2
     for op in ("rms_norm", "rope", "moe_route", "moe_combine"):
         assert op in ops

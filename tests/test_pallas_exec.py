@@ -138,6 +138,109 @@ def test_transformer_block_pallas_bmm_parity():
         _assert_outputs_match(p, e, "transformer_block/gemmini")
 
 
+# -- the batched requantize in the kernel's epilogue ----------------------------
+
+B, M, N = 2, 8, 32
+
+
+def _context_graph(k: int, scale: float) -> ir.Graph:
+    """The attention context: ``requantize(probs @ v)`` over ``B`` instances."""
+    x = ir.input_((B, M, k), "int8", name="probs")
+    v = ir.input_((B, k, N), "int8", name="v")
+    return ir.Graph([ir.requantize(ir.dense(x, v), scale=scale)], name="context")
+
+
+def _extremes(k: int):
+    """Operands at the int8 extremes; instance 0's first row meets ``v``'s
+    column 0 of -128s with -128s, so its accumulator is ``k * 2^14``."""
+    rng = np.random.default_rng(k)
+    x = rng.choice(np.array([-128, 127], np.int8), (B, M, k))
+    v = rng.choice(np.array([-128, 127], np.int8), (B, k, N))
+    x[0, 0] = -128
+    v[0, :, 0] = -128
+    return {"probs": x, "v": v}
+
+
+def _ties(k: int):
+    """Every accumulator is ``64 * odd``: an exact half of 2^-7, of both
+    signs (the column 0 of ``probs`` is ±64, ``v``'s row 0 odd)."""
+    rng = np.random.default_rng(5)
+    x = np.zeros((B, M, k), np.int8)
+    x[:, :, 0] = rng.choice(np.array([-64, 64], np.int8), (B, M))
+    v = rng.integers(-128, 128, (B, k, N)).astype(np.int8)
+    v[:, 0] = 2 * rng.integers(-64, 64, (B, N)) + 1
+    return {"probs": x, "v": v}
+
+
+def _saturating(k: int):
+    rng = np.random.default_rng(6)
+    return {
+        "probs": rng.integers(-128, 128, (B, M, k)).astype(np.int8),
+        "v": rng.integers(-128, 128, (B, k, N)).astype(np.int8),
+    }
+
+
+# (K, scale, feeds, the epilogue runs on the device)
+CONTEXT_CASES = {
+    "extremes_k1024": (1024, 2.0**-18, _extremes, True),
+    "ties_half_even": (16, 2.0**-7, _ties, True),
+    "saturation": (64, 2.0**-7, _saturating, True),
+    "k1025_on_the_host": (1025, 2.0**-18, _extremes, False),
+    "odd_scale_on_the_host": (64, 0.003, _saturating, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CONTEXT_CASES))
+def test_batched_requantize_matches_the_host_bit_for_bit(case):
+    """The quantized batched step gives ``ir.execute_graph``'s bits, dtype
+    included: in the kernel's epilogue where K <= 1024 and the scale is a
+    power of two (int8 synced), else in the host's row blocks after the
+    int32 kernel (int32 synced); the counters say which."""
+    from repro.core import trace
+
+    k, scale, make_feeds, on_device = CONTEXT_CASES[case]
+    feeds = make_feeds(k)
+    want = ir.execute_graph(_context_graph(k, scale), feeds)[0]
+    module = repro.compile(_context_graph(k, scale), Target("tpu_v5e", cache=False))
+    assert [s.op for s in module.plan.steps] == ["generalized_dense"]
+    before = trace.snapshot()
+    got = module.run(feeds)[0]
+    moved = trace.since(before)
+    _assert_outputs_match(got, want, case)
+    rows = B * M
+    assert (moved["bmm_requant_device_rows"], moved["bmm_requant_host_rows"]) == (
+        (rows, 0) if on_device else (0, rows)
+    )
+    assert moved["d2h_bytes"] == rows * N * (1 if on_device else 4)
+    if case == "extremes_k1024":
+        acc = feeds["probs"][0, 0].astype(np.int64) @ feeds["v"][0, :, 0].astype(np.int64)
+        assert acc == 2**24 and got[0, 0, 0] == 64
+    if case == "ties_half_even":
+        assert np.all(np.abs(want) % 2 == 0)  # every k + 1/2 went to the even k
+    if case == "saturation":
+        assert {-128, 127} <= set(np.unique(got).tolist())
+    # the emulated target's float64 epilogue gives the same bits
+    emulated = repro.compile(_context_graph(k, scale), Target("gemmini", cache=False))
+    _assert_outputs_match(emulated.run(feeds)[0], want, f"{case}/gemmini")
+
+
+@pytest.mark.parametrize("name", ["mlp_tiny", "qcnn", "toycar_mlp", "transformer_block"])
+def test_plans_without_a_batched_dense_are_unchanged_by_the_rule(name, monkeypatch):
+    """No batched dense (the unbatched transformer block's context is 2-D):
+    the plan's steps are those it has without ``fuse-batched-requantize``."""
+    from repro.core import passes
+
+    def steps():
+        module = repro.compile(zoo.get_model(name).build(), Target("tpu_v5e", cache=False))
+        return [(s.op, s.lane) for s in module.plan.steps]
+
+    with_rule = steps()
+    without = tuple(r for r in passes.LEGALIZE_RULES if r.name != "fuse-batched-requantize")
+    assert len(without) == len(passes.LEGALIZE_RULES) - 1
+    monkeypatch.setattr(passes, "LEGALIZE_RULES", without)
+    assert steps() == with_rule
+
+
 # -- measured DSE: top-K timing + warm-boot zero-work -------------------------
 
 

@@ -104,6 +104,82 @@ def test_fuse_gelu_activation():
     assert g.outputs[0].attrs["activation"] == "gelu"
 
 
+def _batched(x_dtype="int8", w_dims=3):
+    """The attention context's operands: ``probs [2, 8, 16]`` and ``v``,
+    ``[2, 16, 4]`` (batched) or ``[16, 4]``."""
+    x = ir.input_((2, 8, 16), x_dtype, name="probs")
+    w_shape = (2, 16, 4) if w_dims == 3 else (16, 4)
+    return x, ir.input_(w_shape, x_dtype, name="v")
+
+
+def _batched_feeds(w_dims=3):
+    rng = np.random.default_rng(2)
+    w_shape = (2, 16, 4) if w_dims == 3 else (16, 4)
+    return {
+        "probs": rng.integers(-128, 128, (2, 8, 16)).astype(np.int8),
+        "v": rng.integers(-128, 128, w_shape).astype(np.int8),
+    }
+
+
+@pytest.mark.parametrize("dtype, lo, hi", [("int8", -128, 127), ("int16", -32768, 32767)])
+def test_fuse_batched_requantize_fires_on_minimal_graph(dtype, lo, hi):
+    """requantize(batched int8 dense) becomes one quantized generalized dense
+    with an absent bias, saturating to the requantize's dtype."""
+    def build():
+        return Graph([ir.requantize(ir.dense(*_batched()), scale=2.0**-7, out_dtype=dtype)])
+
+    g = build()
+    counters: dict[str, int] = {}
+    assert apply_rules(g, LEGALIZE_RULES, counters=counters) == 1
+    assert counters == {"fuse-batched-requantize": 1}
+    assert _ops(g) == ["input", "input", "generalized_dense"]
+    gen = g.outputs[0]
+    assert gen.inputs[2] is None and gen.shape == (2, 8, 4) and gen.dtype == dtype
+    assert gen.attrs["quantized"] is True and gen.attrs["requant_scale"] == 2.0**-7
+    assert (gen.attrs["clip_lo"], gen.attrs["clip_hi"]) == (lo, hi)
+    feeds = _batched_feeds()
+    (got,), (want,) = ir.execute_graph(g, feeds), ir.execute_graph(build(), feeds)
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("blocker", ["second_consumer", "graph_output"])
+def test_fuse_batched_requantize_refuses_an_observable_intermediate(blocker):
+    d = ir.dense(*_batched())
+    rq = ir.requantize(d, scale=2.0**-7)
+    g = Graph([rq, ir.relu(d)] if blocker == "second_consumer" else [rq, d])
+    assert apply_rules(g, LEGALIZE_RULES) == 0
+    assert "requantize" in _ops(g) and "dense" in _ops(g)
+
+
+def _clipped_2d():
+    rq = ir.requantize(ir.dense(*_batched(w_dims=2)), scale=2.0**-7)
+    return ir.clip(rq, lo=-128, hi=127)
+
+
+OTHER_CASES = {
+    # float operands: not an int8 GEMM
+    "float": (lambda: ir.requantize(ir.dense(*_batched("float32")), scale=0.5), {}),
+    # a 2-D second operand without a clip: no rule's chain
+    "2d": (lambda: ir.requantize(ir.dense(*_batched(w_dims=2)), scale=2.0**-7), {}),
+    # a 2-D second operand with a clip: the 2-D rule's chain
+    "2d_clip": (_clipped_2d, {"fuse-quantized-epilogue-nobias": 1}),
+    # a requantize to a float dtype saturates nowhere
+    "float_requantize": (
+        lambda: ir.requantize(ir.dense(*_batched()), scale=2.0**-7, out_dtype="float32"),
+        {},
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OTHER_CASES))
+def test_fuse_batched_requantize_leaves_other_chains_to_other_rules(case):
+    build, fired = OTHER_CASES[case]
+    g = Graph([build()])
+    counters: dict[str, int] = {}
+    apply_rules(g, LEGALIZE_RULES, counters=counters)
+    assert counters == fired
+
+
 def test_fold_transpose_transpose_identity_and_composed():
     x = ir.input_((2, 3, 4), "float32", name="x")
     g = Graph([ir.transpose(ir.transpose(x, (2, 1, 0)), (2, 1, 0))])

@@ -6,8 +6,9 @@ accepts it or raises what the chip's compiler would raise — a block that
 does not tile, or more VMEM than the kernel asked for.  That covers ToyCar's
 accelerator steps, as a real ``tpu_v5e`` compile binds them, the
 scheduled GEMMs at widths where the scheduler's VMEM budget matters, the
-device attention epilogue at MusicGen's width, and Mixtral's grouped
-expert GEMMs and device SiLU·mul.
+device attention epilogue at MusicGen's width, Mixtral's grouped
+expert GEMMs and device SiLU·mul, and both cells' attention context with
+its requantize in the kernel's epilogue.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -220,3 +221,36 @@ def test_swiglu_at_mixtral_width_compiles_for_v5e(one_chip, no_compile_cache):
     ).compile()
     assert compiled.out_info.shape == (2048, 14336)
     assert compiled.out_info.dtype == jnp.int8
+
+
+# (instances, rows, K, N) of the attention context at 2 x 512 tokens:
+# Mixtral's 2 x 8 KV groups of 4 stacked query heads, MusicGen's 2 x 24 heads
+CONTEXT_GEMMS = [(16, 2048, 512, 128), (48, 512, 512, 64)]
+
+
+@pytest.mark.parametrize("b,m,k,n", CONTEXT_GEMMS, ids=["mixtral", "musicgen"])
+def test_context_step_with_device_requantize_compiles_for_v5e(
+    b, m, k, n, one_chip, no_compile_cache
+):
+    """The context step as a ``tpu_v5e`` compile binds it: one quantized
+    generalized dense whose kernel requantizes in its epilogue (K = 512,
+    scale 2^-7), compiled per instance."""
+    from repro.core import ir
+
+    probs = ir.input_((b, m, k), "int8", name="probs")
+    v = ir.input_((b, k, n), "int8", name="v")
+    graph = ir.Graph([ir.requantize(ir.dense(probs, v), scale=2.0**-7)])
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(lowering, "pallas_interpret_mode", lambda: False)
+        module = repro.compile(graph, repro.Target("tpu_v5e", cache=False))
+    (op,) = module.ops.values()
+    cfg = op.executor.kernel_config
+    assert op.node.op == "generalized_dense" and cfg.interpret is False
+    assert cfg.requant_scale == 2.0**-7 and cfg.out_dtype == "int8"
+    args = [
+        jax.ShapeDtypeStruct((m, k), jnp.int8, sharding=one_chip),
+        jax.ShapeDtypeStruct((k, n), jnp.int8, sharding=one_chip),
+        None,
+    ]
+    compiled = _compile_for_chip(op.executor.run_kernel, args)
+    assert compiled.out_info.shape == (m, n) and compiled.out_info.dtype == jnp.int8

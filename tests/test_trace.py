@@ -83,6 +83,8 @@ def _per_execution(bucket: int) -> dict[str, int]:
         "expert_rows_peak": 0,
         "swiglu_device_rows": 0,
         "swiglu_host_rows": 0,
+        "bmm_requant_device_rows": 0,
+        "bmm_requant_host_rows": 0,
     }
 
 
